@@ -64,6 +64,10 @@ def main() -> None:
     )
     args = ap.parse_args()
 
+    from repro.backend.compile_cache import use_compile_cache
+
+    use_compile_cache()
+
     if args.list_targets:
         from repro.targets import list_targets, target_info
 

@@ -10,7 +10,7 @@ Each :class:`~repro.core.dispatcher.MappedSegment` becomes ONE fused,
 * **dense anchors with a requant epilogue** route through the Pallas
   int8 GEMM :func:`repro.kernels.matmul_requant` (``rounding="even"``
   reproduces the interpreter's round-half-to-even requant bit-exactly);
-  the DSE block sizes become the kernel's BlockSpecs.
+  the DSE block sizes, snapped to TPU-legal dims, become its BlockSpecs.
 * **everything else** (elementwise chains, pools, structural ops, CPU
   fallback segments) lowers through the reference op library shared with
   the interpreter (``repro.cnn.execute.apply_node``), fused per segment.
@@ -41,6 +41,7 @@ from repro.core import (
 from repro.cnn.execute import apply_node
 from repro.kernels.matmul_requant import matmul_requant
 from repro.kernels.tiled_conv import tiled_conv2d
+from repro.kernels.tpu import LANE, SUBLANE, tpu_block
 
 from .memory import plan_memory
 from .runtime import CompiledModel
@@ -80,14 +81,6 @@ class LoweredSegment:
 # ---------------------------------------------------------------------------
 # Fused executors
 # ---------------------------------------------------------------------------
-
-
-def _divisor_clip(block: int, dim: int, minimum: int = 1) -> int:
-    """Largest divisor of ``dim`` <= block (Pallas needs exact tiling)."""
-    block = max(minimum, min(block, dim))
-    while dim % block:
-        block -= 1
-    return max(block, minimum)
 
 
 def _fused_reference_fn(
@@ -138,7 +131,6 @@ def _tiled_conv_impl(anchor: Node, ksched: KernelSchedule | None, band_tiling: b
 def _pallas_dense_fn(
     seg: MappedSegment,
     ksched: KernelSchedule | None,
-    interpret: bool,
     ref_fn: Callable,
 ):
     """dense(+bias)+requant(+relu) through the Pallas int8 GEMM.
@@ -149,28 +141,33 @@ def _pallas_dense_fn(
     the params supply a requant scale/addend at runtime (which the GEMM
     epilogue does not model), the call falls back to ``ref_fn`` — the
     segment's fused reference executor — instead of silently diverging.
+
+    Returns ``(fn, (bm, bk, bn))``, the BlockSpec dims at the anchor's
+    (B, C, K) geometry: the DSE tile snapped to TPU-legal dims.
     """
     anchor = seg.anchor
     chain_ops = [n.op for n in seg.epilogue]
     has_relu = "relu" in chain_ops
     bias_node = next((n for n in seg.nodes if n.op == "bias_add"), None)
     requant_node = next(n for n in seg.nodes if n.op == "requant")
-    k_out = int(anchor.attr("K", 1) or 1)
 
-    bm = bn = bk = None
-    if ksched is not None:
-        bm = int(ksched.block_of("B", 1))
-        bn = int(ksched.block_of("K", k_out))
-        bk = int(ksched.block_of("C", 1))
+    def blocks_for(m: int, kd: int, n_out: int) -> tuple[int, int, int]:
+        if ksched is None:
+            return m, kd, n_out
+        return (
+            tpu_block(int(ksched.block_of("B", m)), m, SUBLANE),
+            tpu_block(int(ksched.block_of("C", kd)), kd, LANE),
+            tpu_block(int(ksched.block_of("K", n_out)), n_out, LANE),
+        )
 
     def fn(seg_params: dict, x):
         rp = seg_params.get(requant_node.name, {})
         if "scale" in rp or "addend" in rp:
             return ref_fn(seg_params, x)
         x2 = jnp.asarray(x, jnp.float32).reshape(x.shape[0], -1)
-        m, kd = x2.shape
         w = jnp.asarray(seg_params[anchor.name]["w"])  # (K, C)
         n_out = w.shape[0]
+        bm, bk, bn = blocks_for(*x2.shape, n_out)
         a8 = x2.astype(jnp.int8)
         w8 = w.astype(jnp.int8).T  # (C, K)
         if bias_node is not None:
@@ -189,14 +186,14 @@ def _pallas_dense_fn(
             shift=shift,
             relu=has_relu,
             rounding="even",
-            block_m=_divisor_clip(bm or m, m),
-            block_n=_divisor_clip(bn or n_out, n_out),
-            block_k=_divisor_clip(bk or kd, kd),
-            interpret=interpret,
+            block_m=bm,
+            block_n=bn,
+            block_k=bk,
         )
         return y8.astype(jnp.float32)
 
-    return fn
+    geometry = (int(anchor.attr(a, 1) or 1) for a in ("B", "C", "K"))
+    return fn, blocks_for(*geometry)
 
 
 # ---------------------------------------------------------------------------
@@ -238,7 +235,6 @@ def lower(
     *,
     use_pallas: bool = True,
     band_tiling: bool = True,
-    interpret: bool = True,
     allow_spill: bool = True,
     hill_climb_iters: int = 200,
     aot: bool = False,
@@ -252,8 +248,9 @@ def lower(
     collapses convs to one whole-array band: together they select the
     "fused" fidelity — same fused segments and memory plan, but the
     fastest host execution (the default is the HW-faithful execution
-    shape: L1-stripe conv bands + the Pallas int8 GEMM).  ``interpret``
-    is forwarded to the Pallas kernels (True on CPU).  ``aot=True``
+    shape: L1-stripe conv bands + the Pallas int8 GEMM).  The Pallas
+    kernels run interpreted on the CPU backend and compile through Mosaic
+    on a TPU (:func:`repro.kernels.tpu.interpret_mode`).  ``aot=True``
     additionally attaches the whole-graph one-jit AOT executor
     (``CompiledModel.to_aot()``; XLA compile stays lazy until its first
     ``warmup``/``run``), so ``report_dict()`` carries the AOT payload.
@@ -325,7 +322,7 @@ def lower(
             meta["block_oy"] = block_oy
         elif route == "pallas_gemm":
             ref_fn = _fused_reference_fn(seg.nodes, inputs, out_name)
-            fn = _pallas_dense_fn(seg, ksched, interpret, ref_fn)
+            fn, meta["blocks"] = _pallas_dense_fn(seg, ksched, ref_fn)
         else:
             fn = _fused_reference_fn(seg.nodes, inputs, out_name)
         lowered.append(

@@ -2,7 +2,10 @@
 
 Each kernel: <name>.py (pl.pallas_call + explicit BlockSpec VMEM tiling),
 with its jnp oracle in ref.py and the DSE-scheduled jit wrapper in ops.py.
-Validated in interpret mode on CPU; the BlockSpecs target TPU v5e.
+The kernels run under the Pallas interpreter on the CPU backend and
+compile through Mosaic on a TPU (tpu.py derives the mode and the legal
+block dims); tests check them against the oracles on the CPU and compile
+the main path's GEMM for a described v5e.
 """
 
 from . import ops, ref

@@ -20,6 +20,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .tpu import interpret_mode
+
 __all__ = ["flash_attention"]
 
 _NEG_INF = -1e30
@@ -60,7 +62,7 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *, scale, causal,
 
 
 @functools.partial(
-    jax.jit, static_argnames=("causal", "block_q", "block_k", "interpret")
+    jax.jit, static_argnames=("causal", "block_q", "block_k")
 )
 def flash_attention(
     q: jax.Array,  # (B, H, Sq, D)
@@ -70,7 +72,6 @@ def flash_attention(
     causal: bool = True,
     block_q: int = 128,
     block_k: int = 128,
-    interpret: bool = True,
 ) -> jax.Array:
     B, H, Sq, D = q.shape
     _, KV, Sk, _ = k.shape
@@ -94,5 +95,5 @@ def flash_attention(
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, D), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=interpret_mode(),
     )(q, k, v)

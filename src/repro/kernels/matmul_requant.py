@@ -24,6 +24,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .tpu import interpret_mode
+
 __all__ = ["matmul_requant"]
 
 
@@ -47,10 +49,9 @@ def _kernel(a_ref, w_ref, mult_ref, bias_ref, o_ref, acc_ref, *, shift: int, rel
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    a = a_ref[...].astype(jnp.int32)
-    w = w_ref[...].astype(jnp.int32)
+    # int8 operands go to the MXU as they are; Mosaic refuses an int32 dot
     acc_ref[...] += jax.lax.dot_general(
-        a, w, (((1,), (0,)), ((), ())), preferred_element_type=jnp.int32
+        a_ref[...], w_ref[...], (((1,), (0,)), ((), ())), preferred_element_type=jnp.int32
     )
 
     @pl.when(pl.program_id(2) == pl.num_programs(2) - 1)
@@ -82,7 +83,7 @@ def matmul_requant(
     block_m: int = 128,
     block_n: int = 128,
     block_k: int = 128,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jax.Array:
     M, K = a.shape
     K2, N = w.shape
@@ -105,5 +106,5 @@ def matmul_requant(
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((M, N), jnp.int8),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
-        interpret=interpret,
+        interpret=interpret_mode() if interpret is None else interpret,
     )(a, w, mult2, bias2)

@@ -16,6 +16,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .tpu import interpret_mode
+
 __all__ = ["moe_gmm"]
 
 
@@ -36,7 +38,7 @@ def _kernel(x_ref, w_ref, o_ref, acc_ref):
 
 
 @functools.partial(
-    jax.jit, static_argnames=("block_c", "block_f", "block_d", "interpret")
+    jax.jit, static_argnames=("block_c", "block_f", "block_d")
 )
 def moe_gmm(
     x: jax.Array,  # (E, C, D)
@@ -45,7 +47,6 @@ def moe_gmm(
     block_c: int = 128,
     block_f: int = 128,
     block_d: int = 512,
-    interpret: bool = True,
 ) -> jax.Array:
     E, C, D = x.shape
     _, _, F = w.shape
@@ -62,5 +63,5 @@ def moe_gmm(
         out_specs=pl.BlockSpec((1, bc, bf), lambda e, i, j, k: (e, i, j)),
         out_shape=jax.ShapeDtypeStruct((E, C, F), x.dtype),
         scratch_shapes=[pltpu.VMEM((bc, bf), jnp.float32)],
-        interpret=interpret,
+        interpret=interpret_mode(),
     )(x, w)

@@ -3,11 +3,11 @@
 This is the MATCH "specialized codegen branch" for TPU: before a kernel
 runs, its workload is scheduled by the LOMA DSE against the TPU v5e
 MatchTarget; the winning tile sizes become the kernel's BlockSpecs
-(snapped to MXU/VPU-legal quanta via ``tpu_align``).  The mapping is
-cached exactly like the paper caches DSE results per layer geometry.
-
-``use_kernels(False)`` (or interpret-unfriendly shapes) falls back to the
-``ref`` oracles — the "un-matched -> default codegen" path.
+(aligned to MXU/VPU quanta via ``tpu_align``, then snapped to dims that
+tile the array exactly via :func:`repro.kernels.tpu.tpu_block`).  The
+mapping is cached exactly like the paper caches DSE results per layer
+geometry.  Whether a kernel runs interpreted is derived from the backend
+(:func:`repro.kernels.tpu.interpret_mode`), not passed.
 """
 
 from __future__ import annotations
@@ -28,6 +28,7 @@ from .matmul_requant import matmul_requant
 from .moe_gmm import moe_gmm
 from .rglru_scan import rglru_scan
 from .ssd_scan import ssd_scan
+from .tpu import LANE, SUBLANE, tpu_block
 
 __all__ = [
     "scheduled_matmul_requant",
@@ -48,75 +49,66 @@ def _tpu():
     return _TARGET
 
 
-def _divisor_clip(block: int, dim: int, minimum: int = 1) -> int:
-    """Largest divisor of ``dim`` that is <= block (kernels need exact
-    tiling; the DSE's ceil-padding tiles are snapped down)."""
-    block = max(minimum, min(block, dim))
-    while dim % block:
-        block -= 1
-    return max(block, minimum)
-
-
 # ---------------------------------------------------------------------------
 
 
-def scheduled_matmul_requant(a, w, mult, bias, *, shift=8, relu=False, interpret=True):
+def scheduled_matmul_requant(a, w, mult, bias, *, shift=8, relu=False):
     M, K = a.shape
     N = w.shape[1]
     wl = matmul_workload(name=f"mmrq_{M}x{N}x{K}", M=M, N=N, KD=K, a_bytes=1, b_bytes=1, out_bytes=1)
     sched = schedule_for_kernel(
         wl, _tpu().module("mxu"), align={"M": "sublane", "N": "lane", "KD": "lane"}
     )
-    bm = _divisor_clip(sched.block_of("M", M), M)
-    bn = _divisor_clip(sched.block_of("N", N), N)
-    bk = _divisor_clip(sched.block_of("KD", K), K)
+    bm = tpu_block(sched.block_of("M", M), M, SUBLANE)
+    bn = tpu_block(sched.block_of("N", N), N, LANE)
+    bk = tpu_block(sched.block_of("KD", K), K, LANE)
     return matmul_requant(
-        a, w, mult, bias, shift=shift, relu=relu,
-        block_m=bm, block_n=bn, block_k=bk, interpret=interpret,
+        a, w, mult, bias, shift=shift, relu=relu, block_m=bm, block_n=bn, block_k=bk
     )
 
 
-def scheduled_flash_attention(q, k, v, *, causal=True, interpret=True):
+def scheduled_flash_attention(q, k, v, *, causal=True):
     B, H, Sq, D = q.shape
     KV, Sk = k.shape[1], k.shape[2]
     wl = attention_workload(name=f"fa_{B}x{H}x{Sq}x{Sk}x{D}", B=B, H=H, SQ=Sq, SK=Sk, D=D, causal=causal)
     sched = schedule_for_kernel(
         wl, _tpu().module("mxu"), align={"SQ": "sublane", "SK": "lane"}
     )
-    bq = _divisor_clip(sched.block_of("SQ", Sq), Sq)
-    bk = _divisor_clip(sched.block_of("SK", Sk), Sk)
-    return flash_attention(q, k, v, causal=causal, block_q=bq, block_k=bk, interpret=interpret)
+    bq = tpu_block(sched.block_of("SQ", Sq), Sq, SUBLANE)
+    bk = tpu_block(sched.block_of("SK", Sk), Sk, SUBLANE)
+    return flash_attention(q, k, v, causal=causal, block_q=bq, block_k=bk)
 
 
-def scheduled_moe_gmm(x, w, *, interpret=True):
+def scheduled_moe_gmm(x, w):
     E, C, D = x.shape
     F = w.shape[-1]
     wl = matmul_workload(name=f"gmm_{E}x{C}x{D}x{F}", M=C, N=F, KD=D)
     sched = schedule_for_kernel(
         wl, _tpu().module("mxu"), align={"M": "sublane", "N": "lane", "KD": "lane"}
     )
-    bc = _divisor_clip(sched.block_of("M", C), C)
-    bf = _divisor_clip(sched.block_of("N", F), F)
-    bd = _divisor_clip(sched.block_of("KD", D), D)
-    return moe_gmm(x, w, block_c=bc, block_f=bf, block_d=bd, interpret=interpret)
+    bc = tpu_block(sched.block_of("M", C), C, SUBLANE)
+    bf = tpu_block(sched.block_of("N", F), F, LANE)
+    bd = tpu_block(sched.block_of("KD", D), D, LANE)
+    return moe_gmm(x, w, block_c=bc, block_f=bf, block_d=bd)
 
 
-def scheduled_rglru_scan(a, b, *, interpret=True):
+def scheduled_rglru_scan(a, b):
     B, T, W = a.shape
     wl = scan_workload(name=f"lru_{B}x{T}x{W}", B=B, T=T, D=W)
     sched = schedule_for_kernel(wl, _tpu().module("vpu"), align={"D": "lane"})
-    bw = _divisor_clip(sched.block_of("D", W), W)
-    bt = _divisor_clip(sched.block_of("T", T), T)
-    return rglru_scan(a, b, block_w=bw, block_t=bt, interpret=interpret)
+    bw = tpu_block(sched.block_of("D", W), W, LANE)
+    bt = tpu_block(sched.block_of("T", T), T, SUBLANE)
+    return rglru_scan(a, b, block_w=bw, block_t=bt)
 
 
-def scheduled_ssd_scan(xb, a, Bm, Cm, *, interpret=True):
+def scheduled_ssd_scan(xb, a, Bm, Cm):
     B, H, T, P = xb.shape
     N = Bm.shape[-1]
     wl = scan_workload(name=f"ssd_{B}x{H}x{T}", B=B * H, T=T, D=P * N, state=1)
     sched = schedule_for_kernel(wl, _tpu().module("vpu"), align={"T": "sublane"})
-    bt = _divisor_clip(sched.block_of("T", T), T)
-    return ssd_scan(xb, a, Bm, Cm, block_t=bt, interpret=interpret)
+    # T is the last dim of the (1, 1, bt) decay block: a lane dim there
+    bt = tpu_block(sched.block_of("T", T), T, LANE)
+    return ssd_scan(xb, a, Bm, Cm, block_t=bt)
 
 
 def kernel_schedule_table() -> list[dict]:

@@ -20,6 +20,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .tpu import interpret_mode
+
 __all__ = ["rglru_scan"]
 
 
@@ -44,14 +46,13 @@ def _kernel(a_ref, b_ref, o_ref, h_ref, *, bt: int):
     o_ref[0] = out.astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("block_w", "block_t", "interpret"))
+@functools.partial(jax.jit, static_argnames=("block_w", "block_t"))
 def rglru_scan(
     a: jax.Array,  # (B, T, W) decay in (0,1]
     b: jax.Array,  # (B, T, W) input term
     *,
     block_w: int = 128,
     block_t: int = 128,
-    interpret: bool = True,
 ) -> jax.Array:
     B, T, W = a.shape
     bw, bt = min(block_w, W), min(block_t, T)
@@ -67,5 +68,5 @@ def rglru_scan(
         out_specs=pl.BlockSpec((1, bt, bw), lambda bb, wi, ti: (bb, ti, wi)),
         out_shape=jax.ShapeDtypeStruct((B, T, W), jnp.float32),
         scratch_shapes=[pltpu.VMEM((1, bw), jnp.float32)],
-        interpret=interpret,
+        interpret=interpret_mode(),
     )(a, b)

@@ -21,6 +21,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .tpu import interpret_mode
+
 __all__ = ["ssd_scan"]
 
 
@@ -63,7 +65,7 @@ def _kernel(xb_ref, a_ref, b_ref, c_ref, o_ref, h_ref, *, bt: int):
     o_ref[0, 0] = (y_diag + y_off).astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("block_t", "interpret"))
+@functools.partial(jax.jit, static_argnames=("block_t",))
 def ssd_scan(
     xb: jax.Array,  # (B, H, T, P)  x pre-scaled by dt
     a: jax.Array,  # (B, H, T)     dt * A  (<= 0)
@@ -71,7 +73,6 @@ def ssd_scan(
     Cm: jax.Array,  # (B, T, N)
     *,
     block_t: int = 128,
-    interpret: bool = True,
 ) -> jax.Array:
     B, H, T, P = xb.shape
     N = Bm.shape[-1]
@@ -90,5 +91,5 @@ def ssd_scan(
         out_specs=pl.BlockSpec((1, 1, bt, P), lambda b, h, t: (b, h, t, 0)),
         out_shape=jax.ShapeDtypeStruct((B, H, T, P), jnp.float32),
         scratch_shapes=[pltpu.VMEM((P, N), jnp.float32)],
-        interpret=interpret,
+        interpret=interpret_mode(),
     )(xb, a, Bm, Cm)

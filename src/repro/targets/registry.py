@@ -300,15 +300,9 @@ def _load_plugin_file(path: str) -> None:
 
 
 def _load_entry_points() -> None:
-    try:
-        from importlib.metadata import entry_points
-    except ImportError:  # pragma: no cover
-        return
-    try:
-        eps = entry_points(group=ENTRY_POINT_GROUP)
-    except TypeError:  # pragma: no cover - pre-3.10 selectable API
-        eps = entry_points().get(ENTRY_POINT_GROUP, ())
-    for ep in eps:
+    from importlib.metadata import entry_points
+
+    for ep in entry_points(group=ENTRY_POINT_GROUP):
         try:
             with _LOCK:
                 if ep.name in _REGISTRY or ep.name in _ALIASES:

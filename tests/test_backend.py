@@ -240,12 +240,12 @@ def test_matmul_requant_round_even_matches_interpreter_requant():
     w = rng.integers(-4, 5, (64, 32)).astype(np.int8)
     bias = rng.integers(-16, 17, 32).astype(np.int32)
     mult = np.ones(32, np.int32)
-    got = matmul_requant(a, w, mult, bias, shift=5, rounding="even", interpret=True)
+    got = matmul_requant(a, w, mult, bias, shift=5, rounding="even")
     # the interpreter's requant: round(x / 2^S) half-to-even, then clip
     acc = a.astype(np.float32) @ w.astype(np.float32) + bias.astype(np.float32)
     want = np.clip(np.asarray(jnp.round(acc / 32.0)), -128, 127).astype(np.int8)
     assert np.array_equal(np.asarray(got), want)
     # floor mode stays the HW arithmetic-shift oracle
-    got_floor = matmul_requant(a, w, mult, bias, shift=5, rounding="floor", interpret=True)
+    got_floor = matmul_requant(a, w, mult, bias, shift=5, rounding="floor")
     want_floor = matmul_requant_ref(a, w, mult, bias, shift=5)
     assert np.array_equal(np.asarray(got_floor), np.asarray(want_floor))

@@ -10,9 +10,8 @@ from repro.configs import ALL_ARCHS, SHAPES, get_config
 from repro.distributed.autoshard import best_rules, candidate_rules, predict_cell
 from repro.distributed.sharding import ShardingRules, constrain, use_rules
 
-# jax's AbstractMesh takes one ((name, size), ...) shape tuple
-MESH = AbstractMesh((("data", 16), ("model", 16)))
-MESH3 = AbstractMesh((("pod", 2), ("data", 16), ("model", 16)))
+MESH = AbstractMesh((16, 16), ("data", "model"))
+MESH3 = AbstractMesh((2, 16, 16), ("pod", "data", "model"))
 
 
 def test_spec_for_basic():
@@ -101,7 +100,9 @@ def test_constrain_applies_inside_mesh():
     import jax.numpy as jnp
     import numpy as np
 
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    from repro.launch.mesh import make_local_mesh
+
+    mesh = make_local_mesh(1, 1)
     rules = ShardingRules(mesh, {"batch": "data"})
     with use_rules(rules):
         y = jax.jit(lambda x: constrain(x * 2, "batch", None))(jnp.ones((4, 4)))
