@@ -26,9 +26,9 @@ fails deterministically.  The paired on/off end-to-end ratio is also
 reported for cross-checking, but not gated — it inherits the machine's
 noise floor.
 
-PR 9 extends the gate to the always-on serving observability: the
-sketch-backed ``Histogram.observe``, ``WindowedSketch.add``, the flight
-recorder's ``record_request``, and ``SloEngine.record_request`` are each
+PR 9 extends the gate to the always-on serving observability:
+``WindowedSketch.add``, the flight recorder's ``record_request``, and
+``SloEngine.record_request`` are each
 tight-loop measured the same way, and their summed per-request cost is
 gated against the *same* 3% budget relative to one model run (a served
 request costs at least one run, so this bounds the serve-side overhead
@@ -104,14 +104,12 @@ def _per_event_us(fn, batch: int = SPAN_BATCH, rounds: int = SPAN_ROUNDS) -> flo
 
 def _serve_event_costs() -> dict[str, float]:
     """Tight-loop costs of the per-request observability hot path added
-    in PR 9: sketch-backed histogram observe, rolling-window sketch add,
-    flight-recorder request capture, and SLO window accounting."""
+    in PR 9: rolling-window sketch add, flight-recorder request capture,
+    and SLO window accounting."""
     from repro.obs.flight import FlightRecorder
-    from repro.obs.metrics import Histogram
     from repro.obs.sketch import WindowedSketch
     from repro.obs.slo import SloEngine, SloSpec
 
-    hist = Histogram("bench.observe_us")
     win = WindowedSketch(window_s=60.0, intervals=12)
     fl = FlightRecorder()
     slo = SloEngine(
@@ -126,7 +124,6 @@ def _serve_event_costs() -> dict[str, float]:
         return vals[i]
 
     costs = {
-        "hist_observe_us": _per_event_us(lambda: hist.observe(next_val())),
         "windowed_add_us": _per_event_us(lambda: win.add(next_val(), now_s=1.0)),
         "flight_record_request_us": _per_event_us(
             lambda: fl.record_request(

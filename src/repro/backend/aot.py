@@ -405,21 +405,21 @@ class AotModel:
         Bit-exact with ``CompiledModel.run(params, inputs)`` (same fused
         segment bodies, inlined).  First call per input signature pays
         trace + compile (see :meth:`warmup`); subsequent calls reuse the
-        held executable.
+        held executable.  With tracing on, the input coercion is traced
+        as ``aot.coerce`` and the entry lookup plus the executable call
+        as ``aot.dispatch``.
         """
-        coerced = {k: _as_input(v) for k, v in inputs.items()}
-        entry = self.warmup(params, coerced)
-        entry.calls += 1
         tr = obs.get_tracer()
         if tr.enabled:
-            t0_us = tr.now_us()
-            try:
-                return self._run_entry(entry, coerced)
-            finally:
-                tr.complete(
-                    f"aot.run:{self.graph.name}", t0_us, cat="runtime",
-                    lane="run:aot", attrs={"memory": self.memory},
-                )
+            with tr.span("aot.coerce"):
+                coerced = {k: _as_input(v) for k, v in inputs.items()}
+            with tr.span("aot.dispatch", graph=self.graph.name, memory=self.memory):
+                return self._dispatch(params, coerced)
+        return self._dispatch(params, {k: _as_input(v) for k, v in inputs.items()})
+
+    def _dispatch(self, params: dict, coerced: dict) -> dict:
+        entry = self.warmup(params, coerced)
+        entry.calls += 1
         return self._run_entry(entry, coerced)
 
     def _run_entry(self, entry: "AotEntry", coerced: dict) -> dict:

@@ -34,6 +34,13 @@ programmatically::
     obs.enable_tracing("trace.json")
     ... compile + run ...
     obs.save_trace()            # -> Perfetto-loadable JSON
+
+``enable_tracing(profiler=True)`` also writes every scoped span
+(:func:`span`, :meth:`Tracer.span`) into the device profiler's trace as a
+``jax.profiler.TraceAnnotation`` named ``match.<name>``: while a
+``jax.profiler`` trace records, the spans land on the host plane of its
+``.xplane.pb``, on the clock the device events use.  ``Tracer.epoch``
+maps the in-memory timestamps back to ``time.perf_counter``.
 """
 
 from __future__ import annotations
@@ -62,6 +69,8 @@ __all__ = [
 ]
 
 TRACE_ENV = "MATCH_TRACE"
+# scoped spans appear in the device profiler's trace under this prefix
+PROFILER_PREFIX = "match."
 
 # synthetic lane ids start far above real thread idents' low range is
 # irrelevant — they live in their own pid row (see chrome_trace())
@@ -95,7 +104,7 @@ _NULL_SPAN = _NullSpan()
 class Span:
     """One live span; records a Chrome ``"X"`` event on exit."""
 
-    __slots__ = ("_tracer", "name", "cat", "lane", "attrs", "_t0")
+    __slots__ = ("_tracer", "name", "cat", "lane", "attrs", "_t0", "_annotation")
 
     def __init__(self, tracer: "Tracer", name: str, cat: str, lane, attrs):
         self._tracer = tracer
@@ -104,6 +113,7 @@ class Span:
         self.lane = lane
         self.attrs = attrs
         self._t0 = 0.0
+        self._annotation = None
 
     def set(self, **attrs) -> "Span":
         """Attach attributes discovered mid-span (cache stats, counts)."""
@@ -114,6 +124,10 @@ class Span:
         return self
 
     def __enter__(self) -> "Span":
+        annotate = self._tracer.annotate
+        if annotate is not None:
+            self._annotation = annotate(PROFILER_PREFIX + self.name)
+            self._annotation.__enter__()
         self._t0 = self._tracer.now_us()
         return self
 
@@ -127,6 +141,8 @@ class Span:
             tr._tid(self.lane),
             self.attrs,
         )
+        if self._annotation is not None:
+            self._annotation.__exit__(*exc)
         return False
 
 
@@ -136,6 +152,9 @@ class Tracer:
     def __init__(self, enabled: bool = False):
         self.enabled = bool(enabled)
         self.path: str | None = None
+        # jax.profiler.TraceAnnotation while spans also go to the device
+        # profiler (enable_tracing(profiler=True)), else None
+        self.annotate = None
         self._events: deque = deque()  # (name, cat, ts, dur, pid, tid, attrs)
         self._epoch = time.perf_counter()
         self._lock = threading.Lock()
@@ -144,6 +163,12 @@ class Tracer:
         self._thread_names: dict[int, str] = {}
 
     # -- time ------------------------------------------------------------
+    @property
+    def epoch(self) -> float:
+        """The ``time.perf_counter()`` reading that timestamp 0 stands for:
+        an event at ``ts`` microseconds happened at ``epoch + ts * 1e-6``."""
+        return self._epoch
+
     def now_us(self) -> float:
         """Microseconds since this tracer's epoch (trace timebase)."""
         return (time.perf_counter() - self._epoch) * 1e6
@@ -308,11 +333,27 @@ def tracing_enabled() -> bool:
     return _TRACER.enabled
 
 
-def enable_tracing(path: str | os.PathLike | None = None, *, autosave: bool = False) -> Tracer:
+def enable_tracing(
+    path: str | os.PathLike | None = None,
+    *,
+    autosave: bool = False,
+    profiler: bool = False,
+) -> Tracer:
     """Turn on the process tracer; ``path`` sets the default save target.
     ``autosave=True`` registers an atexit save (what ``MATCH_TRACE``
-    does) for callers that cannot reach a shutdown hook."""
+    does) for callers that cannot reach a shutdown hook.
+    ``profiler=True`` also enters a ``jax.profiler.TraceAnnotation``
+    named ``match.<name>`` for every scoped span, so that a recording
+    ``jax.profiler`` trace holds them beside the device's events (jax is
+    imported here, on the first such call, and not before);
+    ``profiler=False`` turns those annotations off again."""
     global _atexit_registered
+    if profiler:
+        from jax.profiler import TraceAnnotation
+
+        _TRACER.annotate = TraceAnnotation
+    else:
+        _TRACER.annotate = None
     _TRACER.enabled = True
     if path is not None:
         _TRACER.path = str(path)
@@ -326,6 +367,7 @@ def enable_tracing(path: str | os.PathLike | None = None, *, autosave: bool = Fa
 
 def disable_tracing() -> None:
     _TRACER.enabled = False
+    _TRACER.annotate = None
 
 
 def save_trace(path: str | os.PathLike | None = None) -> Path:

@@ -75,15 +75,33 @@ class BatchedModel:
 
     # -- stacking -------------------------------------------------------
     def stack(self, inputs_list: Sequence[dict]) -> dict:
-        """Stack per-request input dicts along a new leading slot axis."""
-        from repro.backend.runtime import as_input_array
+        """Stack per-request input dicts along a new leading slot axis.
 
+        Traced as ``batch.stack`` with ``rows`` and ``h2d``: the number of
+        input tensors that came as host arrays, each one a host→device
+        transfer (a ``jax.Array`` is already on the device)."""
         if not inputs_list:
             raise ValueError("cannot stack an empty batch")
-        keys = self.graph.inputs.keys()
+        tr = obs.get_tracer()
+        if not tr.enabled:
+            return self._stack(inputs_list)
+        with tr.span("batch.stack", rows=len(inputs_list)) as sp:
+            stacked = self._stack(inputs_list)
+            sp.set(
+                h2d=sum(
+                    not isinstance(x[k], jax.Array)
+                    for x in inputs_list
+                    for k in self.graph.inputs
+                )
+            )
+        return stacked
+
+    def _stack(self, inputs_list: Sequence[dict]) -> dict:
+        from repro.backend.runtime import as_input_array
+
         return {
             k: jax.numpy.stack([as_input_array(x[k]) for x in inputs_list])
-            for k in keys
+            for k in self.graph.inputs
         }
 
     @staticmethod
@@ -92,11 +110,13 @@ class BatchedModel:
 
         Rows are numpy views over one host transfer per output tensor —
         per-row device slicing would cost ``n`` tiny dispatches per
-        tensor, which at serving rates dwarfs the compute itself."""
+        tensor, which at serving rates dwarfs the compute itself.
+        Traced as ``batch.unstack``."""
         import numpy as np
 
-        host = {k: np.asarray(v) for k, v in outputs.items()}
-        return [{k: v[i] for k, v in host.items()} for i in range(n)]
+        with obs.span("batch.unstack"):
+            host = {k: np.asarray(v) for k, v in outputs.items()}
+            return [{k: v[i] for k, v in host.items()} for i in range(n)]
 
     # -- one AOT entry per batch shape ----------------------------------
     def _signature(self, stacked: dict) -> tuple:
@@ -120,9 +140,11 @@ class BatchedModel:
         def whole_batch(batch_inputs: dict) -> dict:
             env = dict(batch_inputs)
             for ls in segs:
-                env[ls.output_name] = ls.fn(
-                    ls.params_slice(params), *[env[nm] for nm in ls.input_names]
-                )
+                with jax.named_scope(f"seg{ls.index}.{ls.module}"):
+                    env[ls.output_name] = ls.fn(
+                        ls.params_slice(params),
+                        *[env[nm] for nm in ls.input_names],
+                    )
             return {o: env[o] for o in outputs}
 
         t0 = time.perf_counter()
@@ -147,16 +169,17 @@ class BatchedModel:
         """Serve ``inputs_list`` as one packed batch (one host dispatch);
         returns per-request output dicts, row ``i`` bit-exact with
         ``CompiledModel.run(params, inputs_list[i])``."""
-        stacked = self.stack(inputs_list)
-        outs = self.entry(params, stacked)(stacked)
-        return self.unstack(outs, len(inputs_list))
+        return self.unstack(self.run_batch_async(params, inputs_list), len(inputs_list))
 
     def run_batch_async(self, params: dict, inputs_list: Sequence[dict]):
         """Dispatch a packed batch without blocking: returns the stacked
         output dict (jax arrays still materialising on device) — the
-        server's in-flight window blocks on them in completion order."""
+        server's in-flight window blocks on them in completion order.
+        The entry lookup and the executable call are traced as
+        ``batch.dispatch``."""
         stacked = self.stack(inputs_list)
-        return self.entry(params, stacked)(stacked)
+        with obs.span("batch.dispatch"):
+            return self.entry(params, stacked)(stacked)
 
     def entry_stats(self) -> list[dict]:
         """JSON-safe trace/compile cost per AOT batch entry."""

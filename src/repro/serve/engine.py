@@ -19,9 +19,16 @@ replica loop:
   in-flight inputs), or ``stream_depth`` asynchronously dispatched AOT
   batches in ``mode="aot"`` (the fastest host path);
 * every request gets a span on the ``serve:<replica>`` trace lane and
-  feeds the ``serve.*`` metrics (`queue_depth`, `rejected`,
-  `latency_us`, `p99_us`) that ship in ``report_dict()["obs"]``; the
-  replica's aggregate stats land in ``report_dict()["serve"]``;
+  feeds the ``serve.*`` metrics (`completed`, `deadline_misses`; the
+  queue keeps `queue_depth`, `rejected`) that ship in
+  ``report_dict()["obs"]``; the replica's aggregate stats land in
+  ``report_dict()["serve"]``;
+* with tracing on, the serving thread records where a round's time
+  goes: ``serve.take`` (waiting on the queue), ``serve.round`` (attrs
+  ``round``, ``requests``) around ``serve.schedule``, the batching
+  spans, ``serve.block``, ``serve.resolve`` and ``serve.finish_round``,
+  and one ``serve.queue_wait`` per request (attrs ``rid``, ``round``)
+  from its arrival to the take of its round;
 * latency quantiles come from a rolling
   :class:`repro.obs.WindowedSketch` (PR 9) — O(1) per request, bounded
   memory, merge-on-read — not from sorting a sample window on the hot
@@ -257,16 +264,29 @@ class ModelServer:
 
     # -- serving loop ----------------------------------------------------
     def _loop(self) -> None:
+        tr = obs.get_tracer()
+        rounds = itertools.count()
         while True:
-            reqs = self.queue.take(
-                self.batch_slots * self.stream_depth, timeout=_IDLE_WAIT_S
-            )
+            with tr.span("serve.take"):
+                reqs = self.queue.take(
+                    self.batch_slots * self.stream_depth, timeout=_IDLE_WAIT_S
+                )
             if not reqs:
                 if self.queue.closed:
                     return
                 continue
+            rnd = next(rounds)
             try:
-                self._serve_round(reqs)
+                if tr.enabled:
+                    for r in reqs:
+                        tr.complete(
+                            "serve.queue_wait", r.arrival_us, cat="serve",
+                            attrs={"rid": r.rid, "round": rnd},
+                        )
+                    with tr.span("serve.round", round=rnd, requests=len(reqs)):
+                        self._serve_round(reqs)
+                else:
+                    self._serve_round(reqs)
             except BaseException as e:  # resolve, don't kill the replica
                 for r in reqs:
                     if not r.handle.done():
@@ -284,10 +304,11 @@ class ModelServer:
             if not reqs:
                 self._finish_round()
                 return
-        ss = schedule_stream(
-            self.compiled.mapped, [r.priority for r in reqs], order="smith"
-        )
-        ss.validate()
+        with obs.span("serve.schedule"):
+            ss = schedule_stream(
+                self.compiled.mapped, [r.priority for r in reqs], order="smith"
+            )
+            ss.validate()
         self._rounds += 1
         self._last_round = {
             "requests": len(reqs),
@@ -339,19 +360,20 @@ class ModelServer:
     def _finish_round(self) -> None:
         """Round epilogue: evaluate the SLO specs over the rolling
         window, mark the flight recorder's round counters, stamp."""
-        now_us = obs.get_tracer().now_us()
-        if self.slo is not None:
-            self.slo.evaluate(
-                queue_depth=self.queue.depth,
-                target=self.compiled.target.name,
-                now_s=now_us * 1e-6,
+        with obs.span("serve.finish_round"):
+            now_us = obs.get_tracer().now_us()
+            if self.slo is not None:
+                self.slo.evaluate(
+                    queue_depth=self.queue.depth,
+                    target=self.compiled.target.name,
+                    now_s=now_us * 1e-6,
+                )
+            obs.get_flight().record_mark(
+                now_us, f"serve:{self.replica}",
+                queue_depth=self.queue.depth, completed=self._completed,
+                shed=self._shed, rejected=self._rejected,
             )
-        obs.get_flight().record_mark(
-            now_us, f"serve:{self.replica}",
-            queue_depth=self.queue.depth, completed=self._completed,
-            shed=self._shed, rejected=self._rejected,
-        )
-        self._stamp()
+            self._stamp()
 
     def _serve_aot(self, groups: list[list[ServeRequest]]) -> None:
         """One AOT batch executable per group, ``stream_depth`` batches
@@ -406,42 +428,41 @@ class ModelServer:
         return self._pipelined
 
     def _finish(self, g: list[ServeRequest], outs: dict) -> None:
-        jax.block_until_ready(outs)
+        with obs.span("serve.block"):
+            jax.block_until_ready(outs)
         self._resolve(g, outs)
 
     def _resolve(self, g: list[ServeRequest], stacked_outs: dict) -> None:
-        tracer = obs.get_tracer()
-        fl = obs.get_flight()
-        rows = BatchedModel.unstack(stacked_outs, len(g))
-        now = tracer.now_us()
-        now_s = now * 1e-6
-        lat_hist = obs.histogram("serve.latency_us")
-        for r, out in zip(g, rows):
-            r.handle._future.set_result(out)
-            lat = now - r.arrival_us
-            lat_hist.observe(lat)
-            self._lat_sketch.add(lat, now_s=now_s)
-            self._completed += 1
-            obs.counter("serve.completed").inc()
-            missed = r.deadline_us is not None and now > r.deadline_us
-            if missed:
-                self._deadline_misses += 1
-                obs.counter("serve.deadline_misses").inc()
-            if self.slo is not None:
-                self.slo.record_request(lat, missed=missed, now_s=now_s)
-            fl.record_request(
-                rid=r.rid, replica=self.replica, arrival_us=r.arrival_us,
-                latency_us=lat, priority=r.priority,
-                status="missed" if missed else "ok", batch=len(g),
-            )
-            tracer.complete(
-                f"req{r.rid}",
-                r.arrival_us,
-                cat="serve",
-                lane=f"serve:{self.replica}",
-                attrs={"rid": r.rid, "priority": r.priority, "batch": len(g)},
-            )
-        obs.gauge("serve.p99_us").set(self._quantile(0.99))
+        with obs.span("serve.resolve"):
+            tracer = obs.get_tracer()
+            fl = obs.get_flight()
+            rows = BatchedModel.unstack(stacked_outs, len(g))
+            now = tracer.now_us()
+            now_s = now * 1e-6
+            for r, out in zip(g, rows):
+                r.handle._future.set_result(out)
+                lat = now - r.arrival_us
+                self._lat_sketch.add(lat, now_s=now_s)
+                self._completed += 1
+                obs.counter("serve.completed").inc()
+                missed = r.deadline_us is not None and now > r.deadline_us
+                if missed:
+                    self._deadline_misses += 1
+                    obs.counter("serve.deadline_misses").inc()
+                if self.slo is not None:
+                    self.slo.record_request(lat, missed=missed, now_s=now_s)
+                fl.record_request(
+                    rid=r.rid, replica=self.replica, arrival_us=r.arrival_us,
+                    latency_us=lat, priority=r.priority,
+                    status="missed" if missed else "ok", batch=len(g),
+                )
+                tracer.complete(
+                    f"req{r.rid}",
+                    r.arrival_us,
+                    cat="serve",
+                    lane=f"serve:{self.replica}",
+                    attrs={"rid": r.rid, "priority": r.priority, "batch": len(g)},
+                )
 
     # -- reporting -------------------------------------------------------
     @staticmethod
@@ -449,11 +470,6 @@ class ModelServer:
         # the latency window lives on the tracer's timebase (seconds):
         # adds and merge-on-read must agree on the epoch
         return obs.get_tracer().now_us() * 1e-6
-
-    def _quantile(self, q: float) -> float:
-        """Rolling-window latency quantile from the shared sketch —
-        O(buckets) merge-on-read, never a sort of raw samples."""
-        return self._lat_sketch.quantile(q, now_s=self._now_s())
 
     def stats(self) -> dict:
         """JSON-safe per-replica serving stats (also stamped into

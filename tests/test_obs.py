@@ -22,10 +22,10 @@ def _isolate_obs_state():
     """Global tracer/drift state must not leak between tests (or into the
     rest of the suite, which asserts on report_dict contents)."""
     tracer = obs.get_tracer()
-    was_enabled, was_path = tracer.enabled, tracer.path
+    was = tracer.enabled, tracer.path, tracer.annotate
     obs.reset_drift()
     yield
-    tracer.enabled, tracer.path = was_enabled, was_path
+    tracer.enabled, tracer.path, tracer.annotate = was
     obs.reset_drift()
 
 
@@ -134,6 +134,64 @@ def test_enable_disable_tracing_roundtrip(tmp_path):
     assert any(e.get("name") == "unit" for e in doc["traceEvents"])
     obs.disable_tracing()
     assert not obs.tracing_enabled()
+
+
+def test_profiler_spans_land_in_the_device_profilers_trace(tmp_path, host_events):
+    """profiler=True: a scoped span enters a TraceAnnotation, so a
+    recording jax.profiler trace holds it (nested as the spans were),
+    while the in-memory record stays as it was."""
+    tr = obs.enable_tracing(profiler=True)
+    before = len(tr)
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    with jax.profiler.trace(str(tmp_path), profiler_options=opts):
+        with obs.span("outer", cat="compile"):
+            with tr.span("inner", answer=1):
+                time.sleep(0.001)
+    assert [e[0] for e in list(tr._events)[before:]] == ["inner", "outer"]
+    ev = host_events(tmp_path)
+    ((line, lo, hi),) = ev["match.outer"]
+    ((line_in, lo_in, hi_in),) = ev["match.inner"]
+    assert line_in == line and lo <= lo_in < hi_in <= hi
+
+
+def test_profiler_spans_are_off_unless_asked_for(tmp_path, host_events):
+    """MATCH_TRACE-style tracing (no profiler=True) writes nothing into the
+    profiler's trace, and disabling tracing turns the annotations off."""
+    obs.enable_tracing(profiler=True)
+    assert obs.get_tracer().annotate is jax.profiler.TraceAnnotation
+    obs.disable_tracing()
+    assert obs.get_tracer().annotate is None and obs.span("x") is _NULL_SPAN
+    obs.enable_tracing()
+    assert obs.get_tracer().annotate is None
+    with jax.profiler.trace(str(tmp_path)):
+        with obs.span("plain"):
+            pass
+    assert host_events(tmp_path) == {}
+
+
+def test_tracer_epoch_maps_timestamps_to_perf_counter():
+    tr = Tracer(enabled=True)
+    t0 = time.perf_counter()
+    with tr.span("s"):
+        time.sleep(0.002)
+    t1 = time.perf_counter()
+    ((_, _, ts, dur, *_),) = tr._events
+    start = tr.epoch + ts * 1e-6
+    assert t0 <= start and start + dur * 1e-6 <= t1 and dur >= 2000.0
+
+
+def test_obs_imports_jax_only_when_profiler_spans_are_asked_for():
+    import subprocess
+    import sys
+
+    code = (
+        "import sys; from repro import obs; assert 'jax' not in sys.modules; "
+        "obs.enable_tracing(); assert 'jax' not in sys.modules; "
+        "obs.enable_tracing(profiler=True); assert 'jax' in sys.modules"
+    )
+    p = subprocess.run([sys.executable, "-c", code], capture_output=True, text=True, timeout=120)
+    assert p.returncode == 0, p.stderr
 
 
 def test_trace_predicted_schedule_scales_cycles_to_module_clock():

@@ -11,6 +11,7 @@ from functools import lru_cache
 import numpy as np
 import pytest
 
+from repro import obs
 from repro.backend import lower
 from repro.cnn import init_graph_params, mlperf_tiny_networks
 from repro.core import (
@@ -165,6 +166,94 @@ def test_priority_jumps_lane_order_in_a_round():
         for k in ref:
             assert np.array_equal(np.asarray(ref[k]), np.asarray(out[k]))
     cm.attrs.pop("serve")
+
+
+# ---------------------------------------------------------------------------
+# Tracing: the serving round, the batching path and the AOT call
+# ---------------------------------------------------------------------------
+
+
+@pytest.fixture
+def tracer():
+    """The process tracer, put back as it was (on/off, save path,
+    profiler spans) after the test."""
+    tr = obs.get_tracer()
+    was = tr.enabled, tr.path, tr.annotate
+    yield tr
+    tr.enabled, tr.path, tr.annotate = was
+
+
+def _serve_all(cm, params, reqs):
+    with ModelServer(cm, params, batch_slots=3, stream_depth=2) as srv:
+        for h in [srv.submit(r) for r in reqs]:
+            h.result(timeout=120)
+    cm.attrs.pop("serve")
+
+
+def test_disabled_tracing_records_no_event(tracer):
+    cm = _compiled()
+    params, reqs = _io()
+    aot = cm.to_aot()
+    obs.disable_tracing()
+    before = len(tracer)
+    _serve_all(cm, params, reqs)
+    BatchedModel(cm).run_batch_async(params, list(reqs[:2]))
+    aot.run(params, reqs[0])
+    assert len(tracer) == before
+
+
+def test_profiler_trace_holds_the_round_and_what_it_runs(tmp_path, tracer, host_events):
+    """With profiler=True the program's spans land on the host plane of the
+    profiler's trace, nested in the serving thread's round; in memory, a
+    request's queue wait joins its round and its request span."""
+    import jax
+
+    cm = _compiled()
+    params, reqs = _io()
+    aot = cm.to_aot()
+    obs.enable_tracing(profiler=True)
+    before = len(tracer)
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    with jax.profiler.trace(str(tmp_path), profiler_options=opts):
+        _serve_all(cm, params, reqs)
+        aot.run(params, reqs[0])
+    ev = host_events(tmp_path)
+    assert ev.keys() >= {
+        "match.serve.round", "match.serve.schedule", "match.batch.stack", "match.aot.dispatch",
+    }
+    rounds = ev["match.serve.round"]
+    for name in ("match.serve.schedule", "match.batch.stack", "match.batch.dispatch", "match.serve.resolve"):
+        for line, lo, hi in ev[name]:
+            assert any(ln == line and a <= lo and hi <= b for ln, a, b in rounds), name
+
+    events = list(tracer._events)[before:]
+    round_ids = {e[6]["round"] for e in events if e[0] == "serve.round"}
+    waits = [e[6] for e in events if e[0] == "serve.queue_wait"]
+    served = {e[6]["rid"] for e in events if e[0].startswith("req")}
+    assert len(waits) == len(reqs)
+    assert {w["round"] for w in waits} <= round_ids and {w["rid"] for w in waits} == served
+
+
+def test_batch_stack_counts_host_to_device_transfers(tracer):
+    import jax
+
+    cm = _compiled()
+    params, reqs = _io()
+    bm = BatchedModel(cm)
+    obs.enable_tracing()
+    bm.stack(list(reqs[:4]))
+    bm.stack([{k: jax.numpy.asarray(v) for k, v in r.items()} for r in reqs[:3]])
+    stacks = [e[6] for e in list(tracer._events) if e[0] == "batch.stack"]
+    assert stacks[-2:] == [{"rows": 4, "h2d": 4}, {"rows": 3, "h2d": 0}]
+
+
+def test_whole_batch_names_each_segment():
+    cm = _compiled()
+    params, reqs = _io()
+    bm = BatchedModel(cm)
+    text = bm.entry(params, bm.stack(list(reqs[:2]))).as_text()
+    assert f"seg0.{cm.segments[0].module}" in text
 
 
 # ---------------------------------------------------------------------------
