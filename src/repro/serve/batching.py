@@ -30,6 +30,7 @@ import time
 from typing import TYPE_CHECKING, Sequence
 
 import jax
+import numpy as np
 
 from repro import obs
 
@@ -38,6 +39,36 @@ if TYPE_CHECKING:  # repro.backend stays import-light; duck-typed at runtime
     from repro.backend.runtime import CompiledModel
 
 __all__ = ["BatchedModel"]
+
+
+def _host_row(v):
+    """``v`` as a host numpy array, or None where it is not host data: a
+    ``jax.Array`` or another array type keeps the per-row path.  Bare
+    Python data takes float32, as ``as_input_array`` gives it."""
+    if isinstance(v, (np.ndarray, np.generic)):
+        return v
+    if isinstance(v, jax.Array) or hasattr(v, "dtype"):
+        return None
+    return np.asarray(v, np.float32)
+
+
+def _stack_rows(rows: list) -> tuple[jax.Array, int]:
+    """One input's rows stacked along a new leading axis, and the number
+    of host→device copies made.
+
+    Host rows of one shape and dtype are stacked into a fresh host buffer
+    (a batch still being copied never shares it) and put on the default
+    device in one uncommitted copy, canonicalised as ``jnp.asarray`` would.
+    Otherwise each host row is copied on its own and the device stacks."""
+    from repro.backend.runtime import as_input_array
+
+    host = [_host_row(v) for v in rows]
+    if all(h is not None for h in host) and len({(h.shape, h.dtype) for h in host}) == 1:
+        return jax.device_put(np.stack(host)), 1
+    return (
+        jax.numpy.stack([as_input_array(v) for v in rows]),
+        sum(not isinstance(v, jax.Array) for v in rows),
+    )
 
 
 class BatchedModel:
@@ -77,32 +108,36 @@ class BatchedModel:
     def stack(self, inputs_list: Sequence[dict]) -> dict:
         """Stack per-request input dicts along a new leading slot axis.
 
-        Traced as ``batch.stack`` with ``rows`` and ``h2d``: the number of
-        input tensors that came as host arrays, each one a host→device
-        transfer (a ``jax.Array`` is already on the device)."""
+        An input whose rows are all host arrays of one shape and dtype is
+        stacked on the host and copied to the device once; any other input
+        copies each host row on its own and stacks on the device, so rows
+        already on the device stay there.  Traced as ``batch.stack`` with
+        ``rows``; ``h2d``, the number of input tensors that came as host
+        arrays (a ``jax.Array`` is already on the device); and ``copies``,
+        the host→device transfers the stack made."""
         if not inputs_list:
             raise ValueError("cannot stack an empty batch")
         tr = obs.get_tracer()
         if not tr.enabled:
-            return self._stack(inputs_list)
+            return self._stack(inputs_list)[0]
         with tr.span("batch.stack", rows=len(inputs_list)) as sp:
-            stacked = self._stack(inputs_list)
+            stacked, copies = self._stack(inputs_list)
             sp.set(
                 h2d=sum(
                     not isinstance(x[k], jax.Array)
                     for x in inputs_list
                     for k in self.graph.inputs
-                )
+                ),
+                copies=copies,
             )
         return stacked
 
-    def _stack(self, inputs_list: Sequence[dict]) -> dict:
-        from repro.backend.runtime import as_input_array
-
-        return {
-            k: jax.numpy.stack([as_input_array(x[k]) for x in inputs_list])
-            for k in self.graph.inputs
-        }
+    def _stack(self, inputs_list: Sequence[dict]) -> tuple[dict, int]:
+        stacked, copies = {}, 0
+        for k in self.graph.inputs:
+            stacked[k], n = _stack_rows([x[k] for x in inputs_list])
+            copies += n
+        return stacked, copies
 
     @staticmethod
     def unstack(outputs: dict, n: int) -> list[dict]:
@@ -112,8 +147,6 @@ class BatchedModel:
         per-row device slicing would cost ``n`` tiny dispatches per
         tensor, which at serving rates dwarfs the compute itself.
         Traced as ``batch.unstack``."""
-        import numpy as np
-
         with obs.span("batch.unstack"):
             host = {k: np.asarray(v) for k, v in outputs.items()}
             return [{k: v[i] for k, v in host.items()} for i in range(n)]

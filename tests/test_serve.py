@@ -7,7 +7,9 @@ shares the process-wide schedule cache with the other suites."""
 import json
 import threading
 from functools import lru_cache
+from types import SimpleNamespace
 
+import jax
 import numpy as np
 import pytest
 
@@ -77,6 +79,79 @@ def test_run_batch_bit_exact_with_sequential_run():
     for i in range(4):
         ref = cm.run(params, reqs[i])
         assert set(rows[i]) == set(ref)
+        for k in ref:
+            assert np.array_equal(np.asarray(ref[k]), np.asarray(rows[i][k]))
+
+
+def _stub(shape=(2, 3)) -> BatchedModel:
+    """A BatchedModel over one input ``x``: enough to stack rows."""
+    return BatchedModel(SimpleNamespace(graph=SimpleNamespace(inputs={"x": shape})))
+
+
+def _per_row(rows) -> "jax.Array":
+    """The stack as it was made before host rows were stacked on the host:
+    each row coerced to the device on its own, then stacked there."""
+    import jax.numpy as jnp
+
+    from repro.backend.runtime import as_input_array
+
+    return jnp.stack([as_input_array(r) for r in rows])
+
+
+def _assert_same(got, want) -> None:
+    assert got.shape == want.shape and got.dtype == want.dtype
+    assert np.array_equal(np.asarray(got), np.asarray(want))
+
+
+@pytest.mark.parametrize("dtype", ["int8", "uint8", "float32", "float64", "int64", "list"])
+def test_host_rows_stack_once_as_the_per_row_path(dtype):
+    rng = np.random.default_rng(3)
+    rows = [rng.integers(-100, 100, (2, 3)) for _ in range(5)]
+    rows = [r.tolist() for r in rows] if dtype == "list" else [r.astype(dtype) for r in rows]
+    stacked, copies = _stub()._stack([{"x": r} for r in rows])
+    _assert_same(stacked["x"], _per_row(rows))
+    assert copies == 1
+    # uncommitted on the default device, as jnp.asarray leaves an array
+    assert not stacked["x"].committed and stacked["x"].devices() == {jax.devices()[0]}
+
+
+def test_host_scalars_stack_once_as_the_per_row_path():
+    rows = [np.float32(1.5), np.float32(-2.0), np.float32(3.25)]
+    stacked, copies = _stub(())._stack([{"x": r} for r in rows])
+    _assert_same(stacked["x"], _per_row(rows))
+    assert copies == 1
+
+
+@pytest.mark.parametrize("batch", ["one_device_row", "mixed_dtypes", "mixed_shapes"])
+def test_other_batches_keep_the_per_row_path(batch):
+    rng = np.random.default_rng(4)
+    rows = [rng.integers(-100, 100, (2, 3)).astype("float32") for _ in range(4)]
+    if batch == "one_device_row":
+        rows[1] = jax.numpy.asarray(rows[1])
+        copies = 3
+    elif batch == "mixed_dtypes":
+        rows[2] = rows[2].astype("int8")
+        copies = 4
+    else:
+        rows = [rows[0].reshape(2, 3), rows[1].reshape(3, 2)]
+        with pytest.raises(ValueError):
+            _stub()._stack([{"x": r} for r in rows])
+        with pytest.raises(ValueError):
+            _per_row(rows)
+        return
+    stacked, n = _stub()._stack([{"x": r} for r in rows])
+    _assert_same(stacked["x"], _per_row(rows))
+    assert n == copies
+
+
+def test_run_batch_bit_exact_with_a_device_row():
+    cm = _compiled()
+    params, reqs = _io()
+    batch = list(reqs[:3])
+    batch[1] = {k: jax.numpy.asarray(v) for k, v in batch[1].items()}
+    rows = BatchedModel(cm).run_batch(params, batch)
+    for i in range(3):
+        ref = cm.run(params, reqs[i])
         for k in ref:
             assert np.array_equal(np.asarray(ref[k]), np.asarray(rows[i][k]))
 
@@ -236,8 +311,6 @@ def test_profiler_trace_holds_the_round_and_what_it_runs(tmp_path, tracer, host_
 
 
 def test_batch_stack_counts_host_to_device_transfers(tracer):
-    import jax
-
     cm = _compiled()
     params, reqs = _io()
     bm = BatchedModel(cm)
@@ -245,7 +318,7 @@ def test_batch_stack_counts_host_to_device_transfers(tracer):
     bm.stack(list(reqs[:4]))
     bm.stack([{k: jax.numpy.asarray(v) for k, v in r.items()} for r in reqs[:3]])
     stacks = [e[6] for e in list(tracer._events) if e[0] == "batch.stack"]
-    assert stacks[-2:] == [{"rows": 4, "h2d": 4}, {"rows": 3, "h2d": 0}]
+    assert stacks[-2:] == [{"rows": 4, "h2d": 4, "copies": 1}, {"rows": 3, "h2d": 0, "copies": 0}]
 
 
 def test_whole_batch_names_each_segment():
