@@ -36,17 +36,16 @@ def main(argv=None) -> int:
     config = run.load_named(run.HERE, "configs", cell["config"])
     traffic = run.load_named(run.HERE, "traffic", cell["traffic"]) | {"rate_rps": max(rates)}
     driver = run.load_module(run.HERE / "drivers" / f"{traffic['driver']}.py")
+    family = run.load_family(run.HERE, config)
 
     sys.path.insert(0, str(run.CHECKOUT / "src"))
     device = run.device_gate(cell["chips"])
     run.use_compile_cache()
-    from .model import build, int8_pool
     from .spans import Spans
 
     rng = np.random.default_rng(args.seed)
-    pool = int8_pool(config, traffic["pool"], rng)
-    requests = [{k: v[i] for k, v in pool.items()} for i in range(traffic["pool"])]
-    state = driver.setup(build(config), traffic, requests, Spans(annotate=False))
+    _, requests = family.inputs(config, traffic, rng)
+    state = driver.setup(family.build(config)[0], traffic, requests, Spans(annotate=False))
     rows = []
     try:
         for rate in rates:

@@ -1,6 +1,6 @@
 """Mean host milliseconds of the program's ``batch.stack`` span a batch:
-``BatchedModel.stack``, each row's input coercion and the stack onto the
-device."""
+``BatchedModel.stack``, the stack of the batch's host rows on the host and
+its copy to the device, one per input."""
 
 from benchmarks.chip import program
 

@@ -3,18 +3,29 @@
     python3 -m benchmarks.chip --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
 The cell, its configuration, its traffic mix and its metrics are looked up
-by name: the cell in ``BENCHMARK.json``, then ``configs/<config>.json``,
+by name: the cell in ``BENCHMARK.json``, then ``configs/<config>.json``
+(which names a model family, ``families/<family>.py``),
 ``traffic/<traffic>.json`` (which names a driver, ``drivers/<driver>.py``)
-and one reader ``metrics/<metric>.py`` per metric.  A run:
+and one reader ``metrics/<metric>.py`` per metric.  A family gives four
+functions: ``build(config)`` returns the model the drivers take and a dict
+of named set-up parts; ``inputs(config, traffic, rng)`` the pool that the
+check reads and the requests that the driver sends; ``check(config, pool,
+answers, unanswered, rng, rows)`` returns ``(compared, checks)``: whether
+it found answers to compare, and each compared number as a ``{"value",
+"limit"}``; and ``work(config)`` the work of one inference,
+which the readers take as ``ctx["work"]``.  A run:
 
 1. refuses to go on without a TPU and as many chips as the cell asks for;
-2. builds the program from the configuration (``model.build``), makes the
-   cell's seeded input pool, and lets the driver warm up the cell's own
-   shapes; all of that, from process start, is ``setup_s``;
+2. builds the program from the configuration (the family's ``build``),
+   makes the cell's seeded inputs (its ``inputs``), and lets the driver
+   warm up the cell's own shapes; all of that, from process start, is
+   ``setup_s``;
 3. drives the window for ``--seconds`` (with ``--trace 1`` under the
    profiler), reads the device's peak memory and frees the program;
 4. compares a seeded sample of the answers the window delivered to the
-   host with the plain reference (``reference.forward``), exactly;
+   host with the family's plain reference (its ``check``); the run is
+   ``correct`` only where the family compared something and every
+   compared number is within its limit;
 5. prints the metrics (the cell's end-to-end metrics, or with ``--trace 1``
    its per-layer metrics), then the compared numbers beside their limits,
    on standard error and as the last key of the result line.
@@ -40,6 +51,7 @@ import numpy as np
 HERE = Path(__file__).resolve().parent
 CHECKOUT = HERE.parents[1]
 SPEC = CHECKOUT / "BENCHMARK.json"
+FAMILY_PARTS = ("build", "inputs", "check", "work")
 
 
 def load_named(root: Path, kind: str, name: str) -> dict:
@@ -48,13 +60,25 @@ def load_named(root: Path, kind: str, name: str) -> dict:
 
 
 def load_module(path: Path):
-    """A driver or metric reader from its file (metric names hold dots)."""
+    """A driver, family or metric reader from its file (metric names hold dots)."""
     spec = importlib.util.spec_from_file_location(f"bench_chip_{path.parent.name}_{path.stem}", path)
     if spec is None:
         raise FileNotFoundError(path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod
+
+
+def load_family(root: Path, config: dict):
+    """The configuration's model family, ``<root>/families/<family>.py``;
+    raises where the configuration names none or the module lacks a part."""
+    if "family" not in config:
+        raise ValueError(f"configuration {config.get('name')!r} names no family")
+    family = load_module(root / "families" / f"{config['family']}.py")
+    missing = [f for f in FAMILY_PARTS if not callable(getattr(family, f, None))]
+    if missing:
+        raise ValueError(f"family {config['family']!r} lacks {', '.join(missing)}")
+    return family
 
 
 def cell_metrics(spec: dict, cell: str) -> tuple[list[dict], list[dict]]:
@@ -94,26 +118,6 @@ def memory_peak_bytes(chips: int) -> int:
     return max(int(s.get("peak_bytes_in_use", 0)) for s in stats)
 
 
-def check(config: dict, pool: dict, answers: list, unanswered: int, rng, rows: int) -> tuple[bool, dict]:
-    """Compare a seeded sample of the delivered answers with the reference."""
-    from . import reference
-
-    idx = np.concatenate([i for i, _ in answers]) if answers else np.zeros(0, int)
-    got = [r for _, rs in answers for r in rs]
-    pick = np.sort(rng.choice(len(idx), size=min(rows, len(idx)), replace=False))
-    uniq, inv = np.unique(idx[pick], return_inverse=True)
-    want = reference.forward(config, reference.make_weights(config), {k: v[uniq] for k, v in pool.items()})[inv]
-    out = [np.asarray(next(iter(got[p].values())), np.float64) for p in pick]
-    c = reference.compare(np.stack(out) if out else np.zeros((0,) + want.shape[1:]), want)
-    checks = {
-        "max_abs_err": {"value": c["max_abs_err"], "limit": 0.0},
-        "wrong_rows": {"value": c["wrong_rows"], "limit": 0},
-        "unanswered": {"value": unanswered, "limit": 0},
-    }
-    print(f"benchmark: compared {len(pick)} of {len(idx)} answers with the reference", file=sys.stderr)
-    return len(pick) > 0 and all(v["value"] <= v["limit"] for v in checks.values()), checks
-
-
 def main(argv=None, *, root: Path = HERE, spec_path: Path = SPEC, t_start: float | None = None) -> int:
     t_start = time.perf_counter() if t_start is None else t_start
     ap = argparse.ArgumentParser(prog="python3 -m benchmarks.chip")
@@ -135,31 +139,30 @@ def main(argv=None, *, root: Path = HERE, spec_path: Path = SPEC, t_start: float
     wanted = per_layer if args.trace else e2e
     readers = {m["name"]: load_module(root / "metrics" / f"{m['name']}.py") for m in wanted}
     driver = load_module(root / "drivers" / f"{traffic['driver']}.py")
+    family = load_family(root, config)
 
     sys.path.insert(0, str(CHECKOUT / "src"))
     device = device_gate(cell["chips"])
     t_device = time.perf_counter()
-    from . import model as model_mod
     from . import reduce, work
     from .spans import Spans
 
     peak = work.peak_for(device["kind"])
     print(f"benchmark: {cell['name']} on {device['kind']} x{device['count']}, compile cache {use_compile_cache()}", file=sys.stderr)
     pool_rng, order_rng, check_rng = (np.random.default_rng(s) for s in np.random.SeedSequence(args.seed).spawn(3))
-    model = model_mod.build(config)
+    model, parts = family.build(config)
     t_build = time.perf_counter()
-    pool = model_mod.int8_pool(config, traffic["pool"], pool_rng)
-    requests = [{k: v[i] for k, v in pool.items()} for i in range(traffic["pool"])]
+    pool, requests = family.inputs(config, traffic, pool_rng)
     spans = Spans(annotate=bool(args.trace))
     state = driver.setup(model, traffic, requests, spans)
     # what set-up built lives to the end: keep the collector from rescanning
     # it in the window, where a full collection stalls the host for ~50 ms
     gc.collect()
     gc.freeze()
-    setup = {"setup_s": time.perf_counter() - t_start, "dispatch_s": model.dispatch_s, "compile_s": state["compile_s"]}
+    setup = {"setup_s": time.perf_counter() - t_start, "compile_s": state["compile_s"], **parts}
     print(
         f"benchmark: set-up {setup['setup_s']:.2f} s: to the device {t_device - t_start:.2f}, "
-        f"build {t_build - t_device:.2f} (dispatch {model.dispatch_s:.2f}), "
+        f"build {t_build - t_device:.2f} ({', '.join(f'{k} {v:.2f}' for k, v in parts.items())}), "
         f"pool and warm-up {t_start + setup['setup_s'] - t_build:.2f} (compile {state['compile_s']:.2f})",
         file=sys.stderr,
     )
@@ -192,10 +195,12 @@ def main(argv=None, *, root: Path = HERE, spec_path: Path = SPEC, t_start: float
     del model, state
     gc.collect()
 
-    correct, checks = check(config, pool, res["answers"], res["unanswered"], check_rng, traffic["check_rows"])
+    compared, checks = family.check(config, pool, res["answers"], res["unanswered"], check_rng, traffic["check_rows"])
+    # the family gives the numbers and their limits; the harness holds each to its limit
+    correct = bool(compared) and bool(checks) and all(c["value"] <= c["limit"] for c in checks.values())
     ctx = {
         "cell": cell, "config": config, "traffic": traffic, "setup": setup, "run": res, "spans": spans,
-        "trace": trace, "work": work.work(config), "peak": peak,
+        "trace": trace, "work": family.work(config), "peak": peak,
     }
     metrics = {}
     for m in wanted:

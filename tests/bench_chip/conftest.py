@@ -28,7 +28,7 @@ SMALL = {
 
 @pytest.fixture
 def small_root(tmp_path: Path) -> Path:
-    for d in ("configs", "drivers", "metrics", "traffic"):
+    for d in ("configs", "drivers", "families", "metrics", "traffic"):
         shutil.copytree(BENCH / d, tmp_path / d)
     for name, cut in SMALL.items():
         path = tmp_path / "traffic" / f"{name}.json"

@@ -33,6 +33,7 @@ def _alter(monkeypatch, fault: str) -> None:
         ("resnet8_cifar10.offline_b256", "answer_altered"),
         ("resnet8_cifar10.offline_b256", "half_batch"),
         ("resnet8_cifar10.single_stream", "answer_altered"),
+        ("dscnn_kws.single_stream", "answer_altered"),
         ("mobilenetv1_025_vww.server_poisson", "answer_altered"),
         ("mobilenetv1_025_vww.server_poisson", "half_batch"),
     ],

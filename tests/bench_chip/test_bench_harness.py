@@ -71,6 +71,7 @@ def test_files_are_found_by_name(small_root, run_cell, monkeypatch):
         ("resnet8_cifar10.offline_b256", "throughput_ips"),
         ("resnet8_cifar10.single_stream", "latency_p90_us"),
         ("mobilenetv1_025_vww.server_poisson", "server_p90_ms"),
+        ("dscnn_kws.single_stream", "latency_p90_us"),
     ],
 )
 def test_whole_run_is_correct(small_root, run_cell, capsys, cell, metric):

@@ -25,11 +25,13 @@ NEW = {
 def tracer():
     """Every reader turns the program's tracer on as it is imported: put
     the tracer back as it was (on/off, save path, profiler spans, events)
-    so that no other test sees tracing on."""
+    so that no other test sees tracing on.  The test starts with no events:
+    what an earlier test recorded would land in its synthetic window."""
     from repro import obs
 
     tr = obs.get_tracer()
     was = tr.enabled, tr.path, tr.annotate, list(tr._events)
+    tr.clear()
     yield tr
     tr.enabled, tr.path, tr.annotate = was[:3]
     tr.clear()

@@ -12,7 +12,7 @@ from benchmarks.chip import model, reference
 
 BENCH = Path(__file__).resolve().parents[2] / "benchmarks" / "chip"
 
-CONFIGS = ("mobilenetv1_025_vww", "resnet8_cifar10")
+CONFIGS = ("mobilenetv1_025_vww", "resnet8_cifar10", "dscnn_kws")
 
 
 def _config(name):

@@ -12,7 +12,7 @@ BENCH = Path(__file__).resolve().parents[2] / "benchmarks" / "chip"
 
 @pytest.mark.parametrize(
     "name, macs, weight_bytes",
-    [("mobilenetv1_025_vww", 7_489_664, 219_064), ("resnet8_cifar10", 12_501_632, 78_744)],
+    [("mobilenetv1_025_vww", 7_489_664, 219_064), ("resnet8_cifar10", 12_501_632, 78_744), ("dscnn_kws", 2_656_768, 24_368)],
 )
 def test_work_counts(name, macs, weight_bytes):
     w = work.work(json.loads((BENCH / "configs" / f"{name}.json").read_text()))
