@@ -133,12 +133,14 @@ def _chunked_attention(
     q_offset: int | jax.Array = 0,
     window: int | None = None,
     chunk: int = 1024,
+    scale: float | None = None,
 ) -> jax.Array:
-    """Online-softmax attention scanning key chunks; fp32 accumulators."""
+    """Online-softmax attention scanning key chunks; fp32 accumulators.
+    ``scale`` multiplies the scores (default ``1/sqrt(head_dim)``)."""
     B, Sq, H, D = q.shape
     _, Sk, KV, _ = k.shape
     g = H // KV
-    scale = 1.0 / math.sqrt(D)
+    scale = 1.0 / math.sqrt(D) if scale is None else scale
     qf = (q.astype(jnp.float32) * scale).reshape(B, Sq, KV, g, D)
 
     chunk = min(chunk, Sk)
@@ -196,7 +198,7 @@ def attention(
         k = apply_rope(k, sin, cos)
     causal = cfg.causal if causal is None else causal
     out = _chunked_attention(
-        q, k, v, causal=causal, window=window, chunk=min(kv_chunk, S)
+        q, k, v, causal=causal, window=window, chunk=min(kv_chunk, S), scale=cfg.attn_scale
     )
     out = constrain(out.astype(x.dtype), "batch", "seq", "heads", None)
     y = out.reshape(B, S, -1) @ params["wo"]
@@ -229,8 +231,7 @@ def decode_attention(
 
     S_max = k.shape[1]
     g = nh // nkv
-    scale = 1.0 / math.sqrt(hd)
-    qf = (q.astype(jnp.float32) * scale).reshape(B, 1, nkv, g, hd)
+    qf = (q.astype(jnp.float32) * cfg.attn_scale).reshape(B, 1, nkv, g, hd)
     s = jnp.einsum("bqkgd,bskd->bqkgs", qf, k.astype(jnp.float32))
     k_pos = jnp.arange(S_max)
     mask = k_pos <= position

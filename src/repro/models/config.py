@@ -15,6 +15,7 @@ stay O(1) in depth — required to compile granite-34b's 88 layers for a
 from __future__ import annotations
 
 import dataclasses
+import math
 from dataclasses import dataclass, field
 from typing import Literal, Sequence
 
@@ -54,18 +55,38 @@ class ModelConfig:
     top_k: int = 0
     moe_d_ff: int = 0  # per-expert hidden (fine-grained MoE)
     capacity_factor: float = 1.25
-    moe_combine: str = "gather"  # gather | scatter (see EXPERIMENTS §Perf)
-    moe_dispatch: str = "token"  # token | unique_k (§Perf A7: refuted, kept for the log)
+    moe_combine: str = "gather"  # gather | scatter (scatter: refuted, see moe.py)
+    moe_dispatch: str = "token"  # token | unique_k (unique_k: refuted, kept for the log)
+    # the expert layer: False -> moe_ffn (capacity factor, drops overflow);
+    # True -> moe_share_ffn, which drops no token and computes the held
+    # experts densely over every token, weighted by its gates: at decode's
+    # few tokens a step is bound by reading their weights, while a prefill
+    # pays len(experts_held) / top_k times the routed FLOPs
+    moe_dropless: bool = False
+    # the experts this model holds, [first, first + count), of the
+    # n_experts the router scores: one chip's share under expert
+    # parallelism (dropless layer only); () -> all of them
+    expert_share: tuple[int, ...] = ()
+    shared_expert_d_ff: int = 0  # a shared SwiGLU expert beside the routed ones
 
     # SSM (mamba2 SSD)
     ssm_state: int = 128
     ssm_head_dim: int = 64
     ssm_expand: int = 2
     ssm_conv: int = 4
+    ssm_conv_bias: bool = False
+    ssm_chunk: int = 128  # SSD chunk length of the prefill/forward path
+    ssm_ffn: bool = False  # Mamba blocks also carry the FFN / expert layer
 
     # RG-LRU (recurrentgemma)
     lru_width: int = 0  # 0 -> d_model
     conv1d_width: int = 4
+
+    # scalar multipliers (Granite 4.0); 0 / 1 keep the plain forms
+    embedding_multiplier: float = 0.0  # 0 -> sqrt(d_model)
+    attention_multiplier: float = 0.0  # softmax scale; 0 -> 1/sqrt(head_dim)
+    residual_multiplier: float = 1.0  # on every residual branch
+    logits_scaling: float = 1.0  # logits are divided by it
 
     # norms / dtypes
     norm_eps: float = 1e-6
@@ -80,6 +101,10 @@ class ModelConfig:
     frontend_stub: bool = False
 
     # ------------------------------------------------------------------
+    def __post_init__(self):
+        if self.expert_share and not self.moe_dropless:
+            raise ValueError(f"{self.name}: an expert_share needs the dropless expert layer (moe_dropless)")
+
     @property
     def kv_heads(self) -> int:
         return self.n_kv_heads or self.n_heads
@@ -89,8 +114,25 @@ class ModelConfig:
         return self.head_dim or self.d_model // self.n_heads
 
     @property
+    def attn_scale(self) -> float:
+        """Softmax scale of attention scores."""
+        return self.attention_multiplier or 1.0 / math.sqrt(self.head_dim_)
+
+    @property
     def is_moe(self) -> bool:
         return self.n_experts > 0
+
+    @property
+    def experts_held(self) -> range:
+        """The experts whose weights this model holds."""
+        if not self.expert_share:
+            return range(self.n_experts)
+        first, count = self.expert_share
+        return range(first, first + count)
+
+    def has_ffn(self, btype: str) -> bool:
+        """Whether a block of this type is followed by an FFN or expert layer."""
+        return btype != "ssd" or self.ssm_ffn
 
     @property
     def attention_free(self) -> bool:
@@ -131,7 +173,7 @@ class ModelConfig:
     def n_params(self) -> int:
         """Parameter count (embedding included once)."""
         d, v = self.d_model, self.vocab
-        total = v * d  # embed
+        total = v * d + d  # embed, final norm
         if not self.tie_embeddings:
             total += v * d  # lm head
         hd, nh, nkv = self.head_dim_, self.n_heads, self.kv_heads
@@ -148,15 +190,17 @@ class ModelConfig:
                 d_in = self.ssm_expand * d
                 nh_s = d_in // self.ssm_head_dim
                 total += d * (2 * d_in + 2 * self.ssm_state + nh_s) + d_in * d
-                total += self.ssm_conv * (d_in + 2 * self.ssm_state)
-            if t in ("attn", "local_attn", "rglru"):
+                total += (self.ssm_conv + self.ssm_conv_bias) * (d_in + 2 * self.ssm_state)
+                total += 3 * nh_s + d_in  # dt_bias, A_log, D, gated norm
+            if self.has_ffn(t):
                 if self.is_moe:
-                    total += self.n_experts * 3 * d * self.moe_d_ff + d * self.n_experts
+                    total += len(self.experts_held) * 3 * d * self.moe_d_ff + d * self.n_experts
+                    total += 3 * d * self.shared_expert_d_ff
                 else:
                     n_mats = 3 if self.activation in ("swiglu", "geglu") else 2
                     total += n_mats * d * self.d_ff
-            elif t == "ssd":
-                pass  # mamba blocks have no separate MLP
+            else:
+                total -= d  # a Mamba block without FFN has one norm
         return total
 
     def n_active_params(self) -> int:
@@ -164,8 +208,9 @@ class ModelConfig:
         if not self.is_moe:
             return self.n_params()
         full = self.n_params()
-        moe_total = self.n_layers * self.n_experts * 3 * self.d_model * self.moe_d_ff
-        moe_active = self.n_layers * self.top_k * 3 * self.d_model * self.moe_d_ff
+        moe_layers = sum(self.has_ffn(t) for t in self.layer_pattern())
+        moe_total = moe_layers * len(self.experts_held) * 3 * self.d_model * self.moe_d_ff
+        moe_active = moe_layers * self.top_k * 3 * self.d_model * self.moe_d_ff
         return full - moe_total + moe_active
 
     def replace(self, **kw) -> "ModelConfig":

@@ -14,6 +14,14 @@ Dispatch is FLOP-free (argsort/scatter/gather slot assignment rather
 than the GShard one-hot einsum), so MODEL_FLOPS/HLO_FLOPs stays honest;
 dropped tokens (capacity overflow) contribute zero, standard
 capacity-factor semantics.
+
+:func:`moe_share_ffn` (``cfg.moe_dropless``) is the expert layer at one
+chip's share of expert parallelism (``cfg.expert_share``): it routes every
+token over all
+``n_experts`` (top-k logits, softmax over those k), drops none, and
+computes the gated sum of the outputs of the experts it holds, plus the
+shared expert that every chip computes alike.  The exchange that would
+bring the other chips' parts is absent on one chip.
 """
 
 from __future__ import annotations
@@ -25,19 +33,23 @@ import jax.numpy as jnp
 
 from repro.distributed.sharding import constrain
 from repro.models.config import ModelConfig
-from repro.models.layers import ParamSpec
+from repro.models.layers import ParamSpec, mlp, mlp_params
 
-__all__ = ["moe_params", "moe_ffn", "moe_capacity"]
+__all__ = ["moe_params", "moe_ffn", "moe_capacity", "moe_share_ffn"]
 
 
 def moe_params(cfg: ModelConfig) -> dict:
     d, e, f = cfg.d_model, cfg.n_experts, cfg.moe_d_ff
-    return {
+    held = len(cfg.experts_held)
+    p = {
         "router": ParamSpec((d, e), ("embed", None), "float32", scale=0.1),
-        "wi_gate": ParamSpec((e, d, f), ("experts", "embed", "moe_ffn"), cfg.dtype),
-        "wi_up": ParamSpec((e, d, f), ("experts", "embed", "moe_ffn"), cfg.dtype),
-        "wo": ParamSpec((e, f, d), ("experts", "moe_ffn", "embed"), cfg.dtype),
+        "wi_gate": ParamSpec((held, d, f), ("experts", "embed", "moe_ffn"), cfg.dtype),
+        "wi_up": ParamSpec((held, d, f), ("experts", "embed", "moe_ffn"), cfg.dtype),
+        "wo": ParamSpec((held, f, d), ("experts", "moe_ffn", "embed"), cfg.dtype),
     }
+    if cfg.shared_expert_d_ff:
+        p["shared"] = mlp_params(d, cfg.shared_expert_d_ff, "swiglu", cfg.dtype)
+    return p
 
 
 def moe_capacity(cfg: ModelConfig, tokens_per_group: int) -> int:
@@ -81,7 +93,7 @@ def moe_ffn(params: dict, x: jax.Array, cfg: ModelConfig) -> tuple[jax.Array, ja
     # every index in these scatters is UNIQUE (slot = expert*C + position),
     # so both the forward scatters and their transposes (gathers) lower
     # cleanly — a duplicate-index scatter-add here costs ~10x HBM traffic
-    # through XLA's collision-safe lowering (see EXPERIMENTS.md §Perf).
+    # through XLA's collision-safe lowering.
     token_ids = jnp.broadcast_to(jnp.arange(S)[None, :, None], (B, S, K))
     k_ids = jnp.broadcast_to(jnp.arange(K)[None, None, :], (B, S, K))
     tok_k = token_ids * K + k_ids  # (B,S,K) unique per (token, k)
@@ -98,12 +110,13 @@ def moe_ffn(params: dict, x: jax.Array, cfg: ModelConfig) -> tuple[jax.Array, ja
     tok_k_for_slot = tok_k_for_slot[:, : E * C]
     gate_for_slot = gate_for_slot[:, : E * C]
 
-    if getattr(cfg, "moe_dispatch", "unique_k") == "unique_k":
+    if cfg.moe_dispatch == "unique_k":
         # dispatch gather over the (token, k) EXPANDED view: indices are
         # unique (tok_k), so the transpose is a unique-index scatter into
         # (B, S*K, D) followed by a dense sum over K — no duplicate-index
-        # scatter-add (whose collision-safe lowering costs ~10x HBM bytes,
-        # §Perf A3/A7).  The expanded view is a broadcast, free in fwd.
+        # scatter-add (whose collision-safe lowering costs ~10x HBM bytes).
+        # The expanded view is a broadcast, free in fwd.  Refuted as a
+        # speed-up (config.py); the tests compare it with "token".
         xk = jnp.broadcast_to(x[:, :, None, :], (B, S, K, D)).reshape(B, S * K, D)
         # one zero pad row: unfilled slots (index S*K) stay unique and
         # their (zero) cotangents land on the discarded pad row
@@ -129,8 +142,8 @@ def moe_ffn(params: dict, x: jax.Array, cfg: ModelConfig) -> tuple[jax.Array, ja
     eo = eo.reshape(B, E * C, D)
 
     # ---- combine ---------------------------------------------------------
-    if str(cfg_combine := getattr(cfg, "moe_combine", "gather")) == "scatter":
-        # REFUTED alternative (kept for the §Perf log): scatter-SET back to
+    if cfg.moe_combine == "scatter":
+        # REFUTED alternative (kept as a tested formulation): scatter-SET back to
         # (token, k) space with unique indices.  Under GSPMD the sharded
         # scatter lowers to an all-gather/select storm: granite-moe train
         # collective term 1.3 s -> 133 s.  Default stays "gather".
@@ -143,7 +156,7 @@ def moe_ffn(params: dict, x: jax.Array, cfg: ModelConfig) -> tuple[jax.Array, ja
         # routing dropped tokens to a dedicated zero pad row (instead of
         # clip-to-0 collisions), so the transpose is a unique-index
         # scatter — XLA's collision-safe scatter-add lowering cost ~10x
-        # HBM bytes on this layer (§Perf hypothesis A6).  Cotangents of
+        # HBM bytes on this layer.  Cotangents of
         # the pad row are all zero (gate=0), so uniqueness is sound.
         eo_pad = jnp.concatenate([eo, jnp.zeros((B, 1, D), eo.dtype)], axis=1)
         gather_slots = jnp.where(slots >= 0, slots, E * C).reshape(B, S * K)
@@ -154,6 +167,8 @@ def moe_ffn(params: dict, x: jax.Array, cfg: ModelConfig) -> tuple[jax.Array, ja
         tok_out = jax.vmap(_row_gather)(eo_pad, gather_slots)
         tok_out = tok_out.reshape(B, S, K, D)
         y = jnp.sum(tok_out * gates[..., None].astype(tok_out.dtype), axis=2)
+    if "shared" in params:
+        y = y + mlp(params["shared"], x, "swiglu")
     y = constrain(y.astype(x.dtype), "batch", "seq", None)
 
     # ---- load-balancing aux loss (Switch/GShard) ------------------------
@@ -162,3 +177,32 @@ def moe_ffn(params: dict, x: jax.Array, cfg: ModelConfig) -> tuple[jax.Array, ja
     ce = jnp.mean(top1, axis=(0, 1))
     aux = E * jnp.sum(me * ce)
     return y, aux
+
+
+def moe_share_ffn(params: dict, x: jax.Array, cfg: ModelConfig) -> tuple[jax.Array, jax.Array]:
+    """The expert layer at this chip's share: x (B, S, D) -> (y, rows).
+
+    Routing is over all ``cfg.n_experts``: the top-``k`` router logits and
+    a softmax over those k (float32).  No token is dropped.  The held
+    experts are computed densely over every token and weighted by each
+    token's gate for them (zero where the token did not pick them): at
+    decode's few tokens a step is bound by reading the experts' weights,
+    not by their FLOPs.  ``rows`` counts the routed (token, expert) pairs
+    that the held experts computed.  The shared expert is added on every
+    chip alike."""
+    held = cfg.experts_held
+    logits = x.astype(jnp.float32) @ params["router"]  # (B, S, E)
+    top, idx = jax.lax.top_k(logits, cfg.top_k)
+    gates = jax.nn.softmax(top, axis=-1)  # (B, S, k)
+    # gate of each held expert for each token, (B, S, held); one_hot gives
+    # a zero row for an expert that another chip holds
+    local = jax.nn.one_hot(idx - held.start, len(held), dtype=jnp.float32)
+    rows = jnp.sum(local, dtype=jnp.int32)
+    w = jnp.einsum("bsk,bske->bse", gates, local)
+    g = jnp.einsum("bsd,edf->bsef", x, params["wi_gate"])
+    u = jnp.einsum("bsd,edf->bsef", x, params["wi_up"])
+    h = jax.nn.silu(g) * u * w[..., None].astype(x.dtype)
+    y = jnp.einsum("bsef,efd->bsd", h, params["wo"])
+    if "shared" in params:
+        y = y + mlp(params["shared"], x, "swiglu")
+    return constrain(y.astype(x.dtype), "batch", "seq", None), rows

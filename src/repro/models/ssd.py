@@ -32,7 +32,7 @@ def ssd_params(cfg: ModelConfig) -> dict:
     d = cfg.d_model
     d_in, H, P, N = _dims(cfg)
     cw = cfg.ssm_conv
-    return {
+    p = {
         "in_z": ParamSpec((d, d_in), ("embed", "ffn"), cfg.dtype),
         "in_x": ParamSpec((d, d_in), ("embed", "ffn"), cfg.dtype),
         "in_B": ParamSpec((d, N), ("embed", None), cfg.dtype),
@@ -47,6 +47,22 @@ def ssd_params(cfg: ModelConfig) -> dict:
         "norm": ParamSpec((d_in,), ("ffn",), "float32", init="zeros"),
         "out": ParamSpec((d_in, d), ("ffn", "embed"), cfg.dtype),
     }
+    if cfg.ssm_conv_bias:
+        p["conv_bias_x"] = ParamSpec((d_in,), ("ffn",), cfg.dtype, init="zeros")
+        p["conv_bias_B"] = ParamSpec((N,), (None,), cfg.dtype, init="zeros")
+        p["conv_bias_C"] = ParamSpec((N,), (None,), cfg.dtype, init="zeros")
+    return p
+
+
+def _conv(params: dict, name: str, x: jax.Array, state: jax.Array | None = None):
+    """Causal depthwise conv of one of x, B, C (plus its bias, where the
+    block has one), then SiLU; returns (activation, new conv state)."""
+    from repro.models.rglru import _causal_conv1d  # shared depthwise conv
+
+    y, st = _causal_conv1d(x, params[f"conv_{name}"], state)
+    if f"conv_bias_{name}" in params:
+        y = y + params[f"conv_bias_{name}"]
+    return jax.nn.silu(y), st
 
 
 def _segsum(x: jax.Array) -> jax.Array:
@@ -108,12 +124,11 @@ def ssd_chunked_ref(
     return y, final_state
 
 
-def ssd_block(
-    params: dict, x: jax.Array, cfg: ModelConfig, chunk: int = 128, *, return_state: bool = False
-):
-    """Full mamba2 block: (B,T,D) -> (B,T,D) [, final state dict]."""
-    from repro.models.rglru import _causal_conv1d  # shared depthwise conv
-
+def ssd_block(params: dict, x: jax.Array, cfg: ModelConfig, *, return_state: bool = False):
+    """Full mamba2 block: (B,T,D) -> (B,T,D) [, final state dict].  The SSD
+    runs in chunks of ``cfg.ssm_chunk``; a sequence longer than one chunk
+    and not a multiple of it is padded at its end with steps of ``dt = 0``,
+    which neither decay nor feed the state."""
     B_, T, D = x.shape
     d_in, H, P, N = _dims(cfg)
     z = x @ params["in_z"]
@@ -124,17 +139,16 @@ def ssd_block(
     dt_raw = (x @ params["in_dt"]).astype(jnp.float32) + params["dt_bias"]
     dt = jax.nn.softplus(dt_raw)  # (B,T,H)
 
-    xs, cx = _causal_conv1d(xs, params["conv_x"])
-    Bm, cb = _causal_conv1d(Bm, params["conv_B"])
-    Cm, cc = _causal_conv1d(Cm, params["conv_C"])
-    xs = jax.nn.silu(xs)
-    Bm = jax.nn.silu(Bm)
-    Cm = jax.nn.silu(Cm)
+    xs, cx = _conv(params, "x", xs)
+    Bm, cb = _conv(params, "B", Bm)
+    Cm, cc = _conv(params, "C", Cm)
 
     A = -jnp.exp(params["A_log"])  # (H,) negative
     xh = xs.reshape(B_, T, H, P)
-    y, final_state = ssd_chunked_ref(xh, dt, A, Bm, Cm, chunk=min(chunk, T))
-    y = y + xh.astype(jnp.float32) * params["D"][None, None, :, None] * 1.0
+    chunk = min(cfg.ssm_chunk, T)
+    pad = lambda a: jnp.pad(a, ((0, 0), (0, -T % chunk)) + ((0, 0),) * (a.ndim - 2))
+    y, final_state = ssd_chunked_ref(pad(xh), pad(dt), A, pad(Bm), pad(Cm), chunk=chunk)
+    y = y[:, :T] + xh.astype(jnp.float32) * params["D"][None, None, :, None] * 1.0
     y = y.reshape(B_, T, d_in).astype(x.dtype)
 
     y = y * jax.nn.silu(z)  # gated
@@ -163,8 +177,6 @@ def ssd_decode_step(
     state: dict,
     cfg: ModelConfig,
 ) -> tuple[jax.Array, dict]:
-    from repro.models.rglru import _causal_conv1d
-
     B_, _, D = x.shape
     d_in, H, P, N = _dims(cfg)
     z = x @ params["in_z"]
@@ -173,12 +185,12 @@ def ssd_decode_step(
     Cm = x @ params["in_C"]
     dt = jax.nn.softplus((x @ params["in_dt"]).astype(jnp.float32) + params["dt_bias"])
 
-    xs, cx = _causal_conv1d(xs, params["conv_x"], state["conv_x"])
-    Bm, cb = _causal_conv1d(Bm, params["conv_B"], state["conv_B"])
-    Cm, cc = _causal_conv1d(Cm, params["conv_C"], state["conv_C"])
-    xs = jax.nn.silu(xs)[:, 0].reshape(B_, H, P).astype(jnp.float32)
-    Bm = jax.nn.silu(Bm)[:, 0].astype(jnp.float32)  # (B,N)
-    Cm = jax.nn.silu(Cm)[:, 0].astype(jnp.float32)
+    xs, cx = _conv(params, "x", xs, state["conv_x"])
+    Bm, cb = _conv(params, "B", Bm, state["conv_B"])
+    Cm, cc = _conv(params, "C", Cm, state["conv_C"])
+    xs = xs[:, 0].reshape(B_, H, P).astype(jnp.float32)
+    Bm = Bm[:, 0].astype(jnp.float32)  # (B,N)
+    Cm = Cm[:, 0].astype(jnp.float32)
     dt = dt[:, 0]  # (B,H)
 
     A = -jnp.exp(params["A_log"])

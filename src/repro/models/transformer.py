@@ -5,7 +5,14 @@ driven by ``ModelConfig.block_types``:
 
 * ``attn`` / ``local_attn``  — GQA attention (+MLP or MoE)
 * ``rglru``                  — Griffin recurrent block (+MLP)
-* ``ssd``                    — Mamba-2 block (self-contained)
+* ``ssd``                    — Mamba-2 block (self-contained, or followed
+                               by the FFN / expert layer: ``cfg.ssm_ffn``)
+
+Each block adds ``residual_multiplier`` x its mixer's output, then x its
+FFN's (Granite 4.0 scales both branches; 1 elsewhere).  The expert layer
+is ``moe_ffn`` (capacity factor, all experts) or, with
+``cfg.moe_dropless``, ``moe_share_ffn`` (dropless, over the held experts
+of ``cfg.expert_share``, plus a shared one).
 
 Layer stacks are executed with ``jax.lax.scan`` over *stacked* per-layer
 parameters; heterogeneous repeating patterns (recurrentgemma R,R,A) scan
@@ -17,6 +24,7 @@ Entry points:
   loss(params, batch)                   -> scalar (+ MoE aux)
   prefill(params, tokens, cache_len)    -> (last_logits, cache)
   decode_step(params, cache, token, pos)-> (logits, cache)
+  decode(params, cache, token, pos)     -> (logits, cache, expert_rows)
 """
 
 from __future__ import annotations
@@ -33,7 +41,7 @@ from repro.models import attention as attn_mod
 from repro.models.attention import KVCache, attention, decode_attention, mrope_tables, rope_tables
 from repro.models.config import ModelConfig
 from repro.models.layers import ParamSpec, embed_params, init_from_specs, mlp, mlp_params, rmsnorm, spec_shapes
-from repro.models.moe import moe_ffn, moe_params
+from repro.models.moe import moe_ffn, moe_params, moe_share_ffn
 from repro.models.rglru import rglru_block, rglru_decode_step, rglru_params, rglru_state_init
 from repro.models.ssd import ssd_block, ssd_decode_step, ssd_params, ssd_state_init
 
@@ -88,7 +96,8 @@ class LM:
             out["rglru"] = rglru_params(cfg)
         elif btype == "ssd":
             out["ssd"] = ssd_params(cfg)
-            return out  # mamba2 blocks carry no separate MLP
+            if not cfg.has_ffn(btype):
+                return out  # mamba2 blocks carry no separate MLP
         out["norm2"] = ParamSpec((d,), ("embed",), "float32", init="zeros")
         if cfg.is_moe:
             out["moe"] = moe_params(cfg)
@@ -114,12 +123,57 @@ class LM:
     def init(self, rng: jax.Array):
         return init_from_specs(rng, self.param_specs())
 
+    def _layer_slots(self):
+        """(stack key, repeat, block key) of each layer, in layer order."""
+        for i, st in enumerate(self.stacks):
+            for r in range(st.repeats):
+                for j, bt in enumerate(st.pattern):
+                    yield f"stack{i}", r, f"b{j}_{bt}"
+
+    def layers_of(self, params: dict) -> list[dict]:
+        """Each layer's block parameters, unstacked, in layer order."""
+        return [jax.tree.map(lambda a: a[r], params[s][b]) for s, r, b in self._layer_slots()]
+
+    def params_from_layers(self, embed, layers: list[dict], final_norm) -> dict:
+        """The model's parameters from per-layer block dicts in layer order
+        (the inverse of :meth:`layers_of`; tied embeddings only)."""
+        if not self.cfg.tie_embeddings or self.cfg.frontend_stub:
+            raise ValueError("params_from_layers builds tied-embedding token models only")
+        stacks: dict[str, dict[str, list]] = {}
+        for layer, (s, _, b) in zip(layers, self._layer_slots(), strict=True):
+            stacks.setdefault(s, {}).setdefault(b, []).append(layer)
+        params: dict[str, Any] = {"embed": embed, "final_norm": final_norm}
+        for s, blocks in stacks.items():
+            params[s] = {b: jax.tree.map(lambda *a: jnp.stack(a), *reps) for b, reps in blocks.items()}
+        return params
+
     def param_shapes(self):
         return spec_shapes(self.param_specs())
 
     # ------------------------------------------------------------------
     # Block application
     # ------------------------------------------------------------------
+    def _residual(self, x: jax.Array, h: jax.Array) -> jax.Array:
+        m = self.cfg.residual_multiplier
+        return x + h if m == 1.0 else x + h * jnp.asarray(m, h.dtype)
+
+    def _ffn(self, btype: str, bp: dict, x: jax.Array):
+        """The block's FFN or expert layer on its residual stream; returns
+        (x, MoE aux loss, routed rows its held experts computed)."""
+        cfg = self.cfg
+        zero = jnp.zeros((), jnp.int32)
+        if not cfg.has_ffn(btype):
+            return x, jnp.zeros((), jnp.float32), zero
+        h2 = rmsnorm(x, bp["norm2"], cfg.norm_eps)
+        aux, rows = jnp.zeros((), jnp.float32), zero
+        if cfg.moe_dropless:
+            y, rows = moe_share_ffn(bp["moe"], h2, cfg)
+        elif cfg.is_moe:
+            y, aux = moe_ffn(bp["moe"], h2, cfg)
+        else:
+            y = mlp(bp["mlp"], h2, cfg.activation)
+        return self._residual(x, y), aux, rows
+
     def _apply_block(self, btype: str, bp: dict, x: jax.Array, rope, aux):
         cfg = self.cfg
         h = rmsnorm(x, bp["norm1"], cfg.norm_eps)
@@ -127,18 +181,12 @@ class LM:
             sin, cos = rope
             window = cfg.local_window if btype == "local_attn" else None
             h = attention(bp["attn"], h, cfg, sin=sin, cos=cos, window=window)
-            x = x + h
         elif btype == "rglru":
-            x = x + rglru_block(bp["rglru"], h, cfg)
+            h = rglru_block(bp["rglru"], h, cfg)
         elif btype == "ssd":
-            return x + ssd_block(bp["ssd"], h, cfg), aux
-        h2 = rmsnorm(x, bp["norm2"], cfg.norm_eps)
-        if cfg.is_moe:
-            y, a = moe_ffn(bp["moe"], h2, cfg)
-            aux = aux + a
-        else:
-            y = mlp(bp["mlp"], h2, cfg.activation)
-        return x + y, aux
+            h = ssd_block(bp["ssd"], h, cfg)
+        x, a, _ = self._ffn(btype, bp, self._residual(x, h))
+        return x, aux + a
 
     def _maybe_remat(self, fn):
         cfg = self.cfg
@@ -197,8 +245,21 @@ class LM:
                 x = x @ params["frontend"]
         else:
             x = jnp.take(params["embed"], tokens, axis=0)
-            x = x * jnp.asarray(jnp.sqrt(cfg.d_model), x.dtype)  # gemma-style scale
+            x = x * self._embed_scale(x.dtype)
         return constrain(x, "batch", "seq", None)
+
+    def _embed_scale(self, dtype) -> jax.Array:
+        """The embedding's multiplier: the config's, else gemma-style sqrt(d)."""
+        cfg = self.cfg
+        return jnp.asarray(cfg.embedding_multiplier or jnp.sqrt(cfg.d_model), dtype)
+
+    def _logits(self, params: dict, x: jax.Array) -> jax.Array:
+        """LM head on normed hidden states (B, S, D) -> (B, S, V)."""
+        head = params["embed"] if self.cfg.tie_embeddings else params["lm_head"]
+        logits = jnp.einsum("bsd,vd->bsv", x, head)
+        if self.cfg.logits_scaling != 1.0:
+            logits = logits / jnp.asarray(self.cfg.logits_scaling, logits.dtype)
+        return logits
 
     def _rope_for(self, positions: jax.Array | None, B: int, S: int):
         cfg = self.cfg
@@ -236,9 +297,7 @@ class LM:
         x = rmsnorm(x, params["final_norm"], self.cfg.norm_eps)
         if last_only:
             x = x[:, -1:]
-        head = params["embed"] if self.cfg.tie_embeddings else params["lm_head"]
-        logits = jnp.einsum("bsd,vd->bsv", x, head)
-        logits = constrain(logits, "batch", "seq", "vocab")
+        logits = constrain(self._logits(params, x), "batch", "seq", "vocab")
         return logits, aux
 
     def loss(self, params: dict, batch: dict) -> jax.Array:
@@ -326,33 +385,23 @@ class LM:
         return out
 
     def _decode_block(self, btype: str, bp: dict, lc: dict, x, position):
+        """One block of one decode step; returns (x, its cache, routed rows)."""
         cfg = self.cfg
         h = rmsnorm(x, bp["norm1"], cfg.norm_eps)
         if btype in ("attn", "local_attn"):
             window = cfg.local_window if btype == "local_attn" else None
             length = lc["k"].shape[1]
             slot = position % length if btype == "local_attn" else position
-            out, kv = self._decode_attn(bp["attn"], h, lc, slot, position, window)
-            x = x + out
-            lc = kv
+            out, lc = self._decode_attn(bp["attn"], h, lc, slot, position, window)
         elif btype == "rglru":
-            out, st = rglru_decode_step(bp["rglru"], h, lc, cfg)
-            x = x + out
-            lc = st
+            out, lc = rglru_decode_step(bp["rglru"], h, lc, cfg)
         elif btype == "ssd":
-            out, st = ssd_decode_step(bp["ssd"], h, lc, cfg)
-            return x + out, st
-        h2 = rmsnorm(x, bp["norm2"], cfg.norm_eps)
-        if cfg.is_moe:
-            y, _ = moe_ffn(bp["moe"], h2, cfg)
-        else:
-            y = mlp(bp["mlp"], h2, cfg.activation)
-        return x + y, lc
+            out, lc = ssd_decode_step(bp["ssd"], h, lc, cfg)
+        x, _, rows = self._ffn(btype, bp, self._residual(x, out))
+        return x, lc, rows
 
     def _decode_attn(self, ap: dict, x, lc: dict, slot, position, window):
         """Ring-buffer-aware single-token attention."""
-        import math as _m
-
         cfg = self.cfg
         B = x.shape[0]
         hd, nh, nkv = cfg.head_dim_, cfg.n_heads, cfg.kv_heads
@@ -369,8 +418,7 @@ class LM:
         v = constrain(v, "batch", None, "kv_heads", None)
 
         g = nh // nkv
-        scale = 1.0 / _m.sqrt(hd)
-        qf = (q.astype(jnp.float32) * scale).reshape(B, 1, nkv, g, hd)
+        qf = (q.astype(jnp.float32) * cfg.attn_scale).reshape(B, 1, nkv, g, hd)
         s = jnp.einsum("bqkgd,bskd->bqkgs", qf, k.astype(jnp.float32))
         valid = (posbuf >= 0) & (posbuf <= position)
         if window is not None:
@@ -390,31 +438,42 @@ class LM:
         position: jax.Array,  # scalar int32
     ) -> tuple[jax.Array, dict]:
         """One autoregressive step: logits for the next token + new cache."""
+        logits, cache, _ = self.decode(params, cache, tokens, position)
+        return logits, cache
+
+    def decode(self, params: dict, cache: dict, tokens: jax.Array, position: jax.Array):
+        """:meth:`decode_step`, also returning the routed (token, expert)
+        pairs the held experts computed in each layer with an FFN, in layer
+        order: (logits, cache, rows (n_ffn_layers,) int32), or rows of
+        length 0 for a model without a share of experts."""
         cfg = self.cfg
         x = jnp.take(params["embed"], tokens[:, None], axis=0)
-        x = x * jnp.asarray(jnp.sqrt(cfg.d_model), x.dtype)
+        x = x * self._embed_scale(x.dtype)
         x = constrain(x, "batch", None, None)
 
         new_cache: dict[str, Any] = {}
+        rows = []
         for i, st in enumerate(self.stacks):
             sp = params[f"stack{i}"]
             sc = cache[f"stack{i}"]
 
             def body(x, inp):
                 lp, lc = inp
-                lc_out = {}
+                lc_out, r = {}, []
                 for j, bt in enumerate(st.pattern):
                     key = f"b{j}_{bt}"
-                    x, lc_out[key] = self._decode_block(bt, lp[key], lc[key], x, position)
-                return x, lc_out
+                    x, lc_out[key], n = self._decode_block(bt, lp[key], lc[key], x, position)
+                    if cfg.moe_dropless and cfg.has_ffn(bt):
+                        r.append(n)
+                return x, (lc_out, jnp.stack(r) if r else jnp.zeros((0,), jnp.int32))
 
-            x, nc = self._scan_or_loop(body, x, (sp, sc), st.repeats, cfg.scan_layers)
+            x, (nc, r) = self._scan_or_loop(body, x, (sp, sc), st.repeats, cfg.scan_layers)
             new_cache[f"stack{i}"] = nc
+            rows.append(r.reshape(-1))
 
         x = rmsnorm(x, params["final_norm"], cfg.norm_eps)
-        head = params["embed"] if cfg.tie_embeddings else params["lm_head"]
-        logits = jnp.einsum("bsd,vd->bsv", x, head)[:, 0]
-        return constrain(logits, "batch", "vocab"), new_cache
+        logits = self._logits(params, x)[:, 0]
+        return constrain(logits, "batch", "vocab"), new_cache, jnp.concatenate(rows)
 
     def prefill(
         self, params: dict, tokens: jax.Array, max_len: int | None = None
@@ -434,8 +493,7 @@ class LM:
         cache = self.init_cache(B, max_len)
         x, cache = self._forward_filling(params, x, rope, cache)
         x = rmsnorm(x, params["final_norm"], cfg.norm_eps)[:, -1:]
-        head = params["embed"] if cfg.tie_embeddings else params["lm_head"]
-        logits = jnp.einsum("bsd,vd->bsv", x, head)[:, 0]
+        logits = self._logits(params, x)[:, 0]
         return constrain(logits, "batch", "vocab"), cache
 
     def _forward_filling(self, params, x, rope, cache):
@@ -469,19 +527,13 @@ class LM:
                     "pos": pp,
                 }
                 window = cfg.local_window if bt == "local_attn" else None
-                x = x + attention(bp["attn"], h, cfg, sin=sin, cos=cos, window=window)
+                y = attention(bp["attn"], h, cfg, sin=sin, cos=cos, window=window)
             elif bt == "rglru":
                 y, lc_new = rglru_block(bp["rglru"], h, cfg, return_state=True)
-                x = x + y
             elif bt == "ssd":
                 y, lc_new = ssd_block(bp["ssd"], h, cfg, return_state=True)
-                return x + y, lc_new
-            h2 = rmsnorm(x, bp["norm2"], cfg.norm_eps)
-            if cfg.is_moe:
-                y, _ = moe_ffn(bp["moe"], h2, cfg)
-            else:
-                y = mlp(bp["mlp"], h2, cfg.activation)
-            return x + y, lc_new
+            x, _, _ = self._ffn(bt, bp, self._residual(x, y))
+            return x, lc_new
 
         for i, st in enumerate(self.stacks):
             sp = params[f"stack{i}"]
