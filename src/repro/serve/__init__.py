@@ -10,9 +10,10 @@ fast.  A :class:`ModelServer` replica fronts a ``CompiledModel`` with:
   fused segment executors over a slot axis, one AOT-compiled executable
   per batch shape, per-request outputs bit-exact with sequential
   ``CompiledModel.run``;
-* priority/deadline-aware rounds whose lane order is the
-  :func:`repro.pipeline.schedule.schedule_stream` Smith order, checked
-  by the existing ``PipelineSchedule.validate()``;
+* priority/deadline-aware rounds served in the queue's Smith pop
+  order, which :func:`repro.pipeline.schedule.schedule_stream` and the
+  existing ``PipelineSchedule.validate()`` prove when
+  ``stats()["last_round"]`` is read, not every round;
 * per-request spans on the ``serve:<replica>`` lane plus ``serve.*``
   metrics, with replica stats in ``report_dict()["serve"]``;
 * service objectives (PR 9): pass :class:`repro.obs.SloSpec` lists to

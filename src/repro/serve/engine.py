@@ -5,11 +5,13 @@ from ``repro.serving`` and PR 5's software-pipelined runtime — into one
 replica loop:
 
 * an :class:`~repro.serve.queue.AdmissionQueue` bounds waiting work
-  (reject/backpressure) and pops in Smith's-rule priority order, the
-  same order :func:`repro.pipeline.schedule.schedule_stream` proves
-  valid (every round's stream schedule is re-built from the round's
-  actual priorities and ``validate()``-checked, so priority jumps never
-  violate happens-before);
+  (reject/backpressure) and pops in Smith's-rule priority order; a round
+  serves its requests in that pop order and records only their ids and
+  priorities.  :func:`repro.pipeline.schedule.schedule_stream` proves
+  the order valid when ``stats()["last_round"]`` is read, not every
+  round: the round's stream schedule is built from those priorities and
+  ``validate()``-checked once, so priority jumps never violate
+  happens-before;
 * :class:`~repro.serve.batching.BatchedModel` packs up to
   ``batch_slots`` requests into one vmapped execution, with one
   AOT-compiled executable per batch shape;
@@ -25,10 +27,13 @@ replica loop:
   ``report_dict()["serve"]``;
 * with tracing on, the serving thread records where a round's time
   goes: ``serve.take`` (waiting on the queue), ``serve.round`` (attrs
-  ``round``, ``requests``) around ``serve.schedule``, the batching
-  spans, ``serve.block``, ``serve.resolve`` and ``serve.finish_round``,
-  and one ``serve.queue_wait`` per request (attrs ``rid``, ``round``)
-  from its arrival to the take of its round;
+  ``round``, ``requests``) around ``serve.schedule`` (recording the
+  round's order), the batching spans, ``serve.block``,
+  ``serve.resolve`` and ``serve.finish_round``, and one
+  ``serve.queue_wait`` per request (attrs ``rid``, ``round``) from its
+  arrival to the take of its round; reading ``last_round`` builds its
+  schedule under ``serve.last_round`` and counts
+  ``serve.last_round_builds``;
 * latency quantiles come from a rolling
   :class:`repro.obs.WindowedSketch` (PR 9) — O(1) per request, bounded
   memory, merge-on-read — not from sorting a sample window on the hot
@@ -155,7 +160,10 @@ class ModelServer:
         self._lat_sketch = obs.WindowedSketch(
             window_s=float(slo_window_s), intervals=12, relative_accuracy=0.01
         )
-        self._last_round: dict = {}
+        # the last round as served: (round, rids, priorities) in pop
+        # order, and its stats()["last_round"] figures once built
+        self._round: tuple = (0, (), ())
+        self._last_round: tuple[int, dict] = (0, {})
         # declarative service objectives, evaluated once per round over
         # the same rolling window; verdicts publish process-wide into
         # report_dict()["obs"]["slo"] under this replica's engine name
@@ -205,7 +213,7 @@ class ModelServer:
                     ServeDrainWarning,
                     logger="serve",
                 )
-        self._stamp()
+        self.compiled.attrs["serve"] = self.stats()
 
     def warmup(self, example_inputs: dict) -> "ModelServer":
         """Trace + compile the full-batch AOT entry (and the pipeline
@@ -293,29 +301,21 @@ class ModelServer:
                         r.handle._future.set_exception(e)
 
     def _serve_round(self, reqs: list[ServeRequest]) -> None:
-        # the round's stream schedule: requests in the queue's pop order
-        # with their real weights — Smith order by construction, and
-        # validate() proves priority jumps never break happens-before or
-        # per-module serialisation
-        from repro.pipeline.schedule import schedule_stream
-
         if self.shed_expired:
             reqs = self._shed_expired(reqs)
             if not reqs:
                 self._finish_round()
                 return
+        # requests go out in the queue's pop order (Smith order by
+        # construction); the round records that order and the weights,
+        # and stats() proves it with the stream schedule when read
         with obs.span("serve.schedule"):
-            ss = schedule_stream(
-                self.compiled.mapped, [r.priority for r in reqs], order="smith"
+            self._rounds += 1
+            self._round = (
+                self._rounds,
+                tuple(r.rid for r in reqs),
+                tuple(r.priority for r in reqs),
             )
-            ss.validate()
-        self._rounds += 1
-        self._last_round = {
-            "requests": len(reqs),
-            "rids": [r.rid for r in reqs],
-            "weighted_completion_cycles": ss.attrs["weighted_completion"],
-            "makespan_cycles": ss.makespan,
-        }
         groups = [
             reqs[i : i + self.batch_slots]
             for i in range(0, len(reqs), self.batch_slots)
@@ -359,7 +359,8 @@ class ModelServer:
 
     def _finish_round(self) -> None:
         """Round epilogue: evaluate the SLO specs over the rolling
-        window, mark the flight recorder's round counters, stamp."""
+        window, mark the flight recorder's round counters, stamp the
+        stats but ``last_round`` (``close()`` stamps it)."""
         with obs.span("serve.finish_round"):
             now_us = obs.get_tracer().now_us()
             if self.slo is not None:
@@ -373,7 +374,7 @@ class ModelServer:
                 queue_depth=self.queue.depth, completed=self._completed,
                 shed=self._shed, rejected=self._rejected,
             )
-            self._stamp()
+            self.compiled.attrs["serve"] = self._base_stats()
 
     def _serve_aot(self, groups: list[list[ServeRequest]]) -> None:
         """One AOT batch executable per group, ``stream_depth`` batches
@@ -472,8 +473,12 @@ class ModelServer:
         return obs.get_tracer().now_us() * 1e-6
 
     def stats(self) -> dict:
-        """JSON-safe per-replica serving stats (also stamped into
-        ``compiled.attrs["serve"]`` → ``report_dict()["serve"]["engine"]``)."""
+        """JSON-safe per-replica serving stats (stamped by ``close()`` into
+        ``compiled.attrs["serve"]`` → ``report_dict()["serve"]["engine"]``;
+        each round stamps all of them but ``last_round``)."""
+        return self._base_stats() | {"last_round": self._last_round_stats()}
+
+    def _base_stats(self) -> dict:
         return {
             "replica": self.replica,
             "mode": self.mode,
@@ -492,9 +497,34 @@ class ModelServer:
             "drained": self._drained,
             "latency_us": self._latency_stats(),
             "slo": self.slo.to_dict() if self.slo is not None else None,
-            "last_round": dict(self._last_round),
             "entries": self.batched.entry_stats(),
         }
+
+    def _last_round_stats(self) -> dict:
+        """The ``stats()["last_round"]`` payload: the last round's stream
+        schedule over its requests' priorities in pop order, built and
+        ``validate()``-checked the first time that round is read — its
+        Smith order proves priority jumps never break happens-before or
+        per-module serialisation."""
+        rnd, rids, priorities = self._round
+        built, figures = self._last_round
+        if rnd and built != rnd:
+            from repro.pipeline.schedule import schedule_stream
+
+            with obs.span("serve.last_round", round=rnd):
+                ss = schedule_stream(
+                    self.compiled.mapped, list(priorities), order="smith"
+                )
+                ss.validate()
+            obs.counter("serve.last_round_builds").inc()
+            figures = {
+                "requests": len(rids),
+                "rids": list(rids),
+                "weighted_completion_cycles": ss.attrs["weighted_completion"],
+                "makespan_cycles": ss.makespan,
+            }
+            self._last_round = (rnd, figures)
+        return dict(figures)
 
     def _latency_stats(self) -> dict:
         """The ``stats()["latency_us"]`` payload: same count/p50/p99/mean
@@ -510,6 +540,3 @@ class ModelServer:
             "window_s": self._lat_sketch.window_s,
             "relative_accuracy": merged.relative_accuracy,
         }
-
-    def _stamp(self) -> None:
-        self.compiled.attrs["serve"] = self.stats()

@@ -243,6 +243,75 @@ def test_priority_jumps_lane_order_in_a_round():
     cm.attrs.pop("serve")
 
 
+def _pinned_server(cm, params, **kw) -> ModelServer:
+    """A server whose worker is a finished thread, so the test, not the
+    loop, drives the rounds."""
+    srv = ModelServer(cm, params, **kw)
+    t = threading.Thread(target=lambda: None)
+    t.start()
+    t.join()
+    srv._thread = t
+    return srv
+
+
+def _serve_one_round(srv, reqs, priorities) -> list[ServeRequest]:
+    handles = [srv.submit(reqs[i], priority=p) for i, p in enumerate(priorities)]
+    batch = srv.queue.take(8, timeout=0)
+    srv._serve_round(batch)
+    for h in handles:
+        h.result(timeout=120)
+    return batch
+
+
+@pytest.mark.parametrize("mode", ["aot", "pipeline"])
+def test_rounds_build_no_schedule_and_last_round_builds_it(monkeypatch, mode):
+    import repro.pipeline.schedule as schedule
+
+    cm = _compiled()
+    params, reqs = _io()
+    real, calls = schedule.schedule_stream, []
+
+    def counting(*args, **kwargs):
+        calls.append(args)
+        return real(*args, **kwargs)
+
+    monkeypatch.setattr(schedule, "schedule_stream", counting)
+    srv = _pinned_server(cm, params, batch_slots=2, stream_depth=2, mode=mode)
+    _serve_one_round(srv, reqs, (1.0, 3.0, 2.0))
+    batch = _serve_one_round(srv, reqs, (2.0, 1.0, 1.0, 4.0))
+    assert calls == []
+    assert "last_round" not in cm.attrs["serve"]  # the round's stamp builds nothing
+    want = real(cm.mapped, [r.priority for r in batch], order="smith")
+    want.validate()
+    assert srv.stats()["last_round"] == {
+        "requests": 4,
+        "rids": [r.rid for r in batch],
+        "weighted_completion_cycles": want.attrs["weighted_completion"],
+        "makespan_cycles": want.makespan,
+    }
+    assert [r.rid for r in batch] == [6, 3, 4, 5]  # Smith order, FIFO ties
+    assert len(calls) == 1
+    cm.attrs.pop("serve")
+
+
+def test_last_round_builds_once_a_round():
+    cm = _compiled()
+    params, reqs = _io()
+    builds = obs.counter("serve.last_round_builds")
+    srv = _pinned_server(cm, params, batch_slots=2, stream_depth=2)
+    before = builds.value
+    assert srv.stats()["last_round"] == {} and builds.value == before
+    _serve_one_round(srv, reqs, (1.0, 2.0))
+    first, second = srv.stats()["last_round"], srv.stats()["last_round"]
+    assert builds.value - before == 1 and first == second
+    _serve_one_round(srv, reqs, (1.0,))
+    srv.stats()
+    srv.close()
+    assert builds.value - before == 2
+    assert cm.attrs["serve"]["last_round"]["rids"] == [2]
+    cm.attrs.pop("serve")
+
+
 # ---------------------------------------------------------------------------
 # Tracing: the serving round, the batching path and the AOT call
 # ---------------------------------------------------------------------------
