@@ -105,8 +105,7 @@ if "--aot" in sys.argv[1:]:
     print(f"dispatch overhead: {ov['dispatch_overhead_per_segment_us']:9.2f} us/segment "
           f"-> {ov['per_segment_path_us'] / max(ov['aot_us'], 1e-9):.2f}x speedup")
     entry = next(iter(aot._entries.values()))
-    print(f"trace {entry.trace_us/1e3:.1f} ms, XLA compile {entry.compile_us/1e3:.1f} ms, "
-          f"donation mode {aot.memory!r}")
+    print(f"trace {entry.trace_us/1e3:.1f} ms, XLA compile {entry.compile_us/1e3:.1f} ms")
 
 # 3c'. request-level serving over the compiled pipeline (PR 8)
 if "--serve" in sys.argv[1:]:

@@ -15,21 +15,13 @@ this package walks a :class:`~repro.core.dispatcher.MappedGraph` and
   report, golden-checked bit-exact against the ``repro.cnn`` interpreter
   (:mod:`repro.backend.runtime`), and
 * **fuses the whole graph into one jitted AOT executable** — all segments
-  inlined in schedule order, zero per-segment host dispatch, the static
-  memory plan expressible as a donated arena with double-buffered
-  cross-module staging (:mod:`repro.backend.aot`).
+  inlined in schedule order, zero per-segment host dispatch,
+  intermediates left to XLA's buffer assignment (:mod:`repro.backend.aot`).
 """
 
-from .aot import (
-    AotCompileError,
-    AotEntry,
-    AotModel,
-    ChainExecutor,
-    build_chains,
-    compile_aot,
-)
+from .aot import AotCompileError, AotEntry, AotModel, compile_aot
 from .lower import LoweredSegment, LoweringError, lower
-from .memory import ArenaView, BufferAlloc, MemoryPlan, MemoryPlanError, plan_memory
+from .memory import BufferAlloc, MemoryPlan, MemoryPlanError, plan_memory
 from .runtime import (
     CompiledModel,
     DivergenceReport,
@@ -44,7 +36,6 @@ __all__ = [
     "LoweredSegment",
     "LoweringError",
     "plan_memory",
-    "ArenaView",
     "MemoryPlan",
     "MemoryPlanError",
     "BufferAlloc",
@@ -57,7 +48,5 @@ __all__ = [
     "AotCompileError",
     "AotEntry",
     "AotModel",
-    "ChainExecutor",
-    "build_chains",
     "compile_aot",
 ]

@@ -6,9 +6,7 @@ host round-trips dominate end-to-end latency.  This module fuses ALL
 lowered segments into **one** XLA program executed without returning to
 Python between segments: the moral equivalent of upstream MATCH's
 generated C graph runner around a static USMP memory plan
-(``static_mem_plan="hill_climb"``, ``tir.InjectDoubleBuffer``,
-``tir.use_async_copy``) and of HTVM's double-buffered accelerator
-handoff.
+(``static_mem_plan="hill_climb"``).
 
 Design points:
 
@@ -25,25 +23,11 @@ Design points:
 * **AOT compile, paid once.**  ``jax.jit(...).lower(...).compile()``
   produces a held executable keyed by the input signature; ``warmup()``
   pays trace+compile explicitly, ``run()`` reuses the executable.
-* **The static MemoryPlan survives into the executable.**
-  ``memory="arena"`` threads one flat, *donated* arena buffer through
-  the program: every planned buffer is stored at its first-fit /
-  hill-climb offset (:meth:`MemoryPlan.arena_view` — byte coordinates
-  scaled to the host element width, disjointness preserved verbatim) and
-  XLA updates the donated buffer in place, so the plan's offsets are the
-  executable's offsets instead of being re-derived by XLA's own buffer
-  assignment.  ``memory="xla"`` (the default, and the fastest host
-  path) keeps intermediates as SSA values — XLA's buffer assignment
-  then owns the aliasing, which ``stats()`` reports as plan coverage.
-* **Cross-module boundaries are double-buffer staged.**  Consecutive
-  segments on different execution modules mirror the pipeline
-  scheduler's ``transfer_cycles`` accounting: in arena mode the
-  boundary tensor lands in one of two alternating staging slots
-  appended to the arena (classic double buffering — slot ``k%2`` is
-  written while slot ``(k+1)%2`` is still being read), and ``stats()``
-  carries the predicted transfer/compute overlap either way.  On the
-  jax host runtime the copy is a dataflow op XLA is free to schedule
-  concurrently (async-copy on real accelerator backends).
+* **Intermediates are left to XLA.**  Every intermediate is an SSA value
+  inside the one program, so XLA's buffer assignment re-derives the
+  aliasing the static :class:`~repro.backend.memory.MemoryPlan` decided;
+  ``stats()`` reports the plan's aliasing beside the share of its bytes
+  the executable holds.
 """
 
 from __future__ import annotations
@@ -51,8 +35,7 @@ from __future__ import annotations
 import threading
 import time
 from dataclasses import dataclass, field
-from functools import partial
-from typing import TYPE_CHECKING, Callable, Sequence
+from typing import TYPE_CHECKING, Callable
 
 import jax
 import jax.numpy as jnp
@@ -61,16 +44,13 @@ import numpy as np
 from repro import obs
 
 if TYPE_CHECKING:  # avoid circular imports at module load
-    from .lower import LoweredSegment
     from .runtime import CompiledModel
 
 __all__ = [
     "AotCompileError",
     "AotEntry",
     "AotModel",
-    "ChainExecutor",
     "compile_aot",
-    "build_chains",
 ]
 
 
@@ -103,10 +83,6 @@ class AotEntry:
     trace_us: float
     compile_us: float
     params: dict = field(repr=False)  # strong ref: keeps the bake valid
-    arena: object = field(default=None, repr=False)  # donated, arena mode
-    arena_elems: int = 0
-    arena_fallbacks: tuple[str, ...] = ()
-    donation_honored: bool | None = None
     calls: int = 0
 
     def executable_stats(self) -> dict:
@@ -132,9 +108,6 @@ class AotEntry:
             "inputs": [list(s) for s in self.signature],
             "trace_us": self.trace_us,
             "compile_us": self.compile_us,
-            "arena_elems": self.arena_elems,
-            "arena_fallbacks": list(self.arena_fallbacks),
-            "donation_honored": self.donation_honored,
             "calls": self.calls,
             "executable": self.executable_stats(),
         }
@@ -143,55 +116,16 @@ class AotEntry:
 class AotModel:
     """A CompiledModel fused into one jitted whole-graph program.
 
-    ``memory="xla"`` (default) leaves intermediate buffers to XLA's own
-    assignment — fastest host path; ``memory="arena"`` expresses the
-    static :class:`MemoryPlan` literally (one donated flat arena, every
-    buffer at its planned offset, cross-module boundaries staged through
-    two alternating double-buffer slots).  ``donate_inputs=True``
-    additionally donates the graph-input buffers (safe when callers pass
-    numpy arrays, which are copied to device per call; a donated *jax*
-    array is consumed).  Donation falls back silently — never an error —
-    on backends that do not honor it; ``stats()['donation']`` records
-    what was requested and what stuck.
+    Intermediate buffers stay inside the executable, where XLA's buffer
+    assignment owns them; ``stats()`` reports the share of the static
+    :class:`MemoryPlan`'s bytes that this covers.
     """
 
-    def __init__(
-        self,
-        compiled: "CompiledModel",
-        *,
-        memory: str = "xla",
-        donate_inputs: bool = False,
-        staging: bool = True,
-    ):
-        if memory not in ("xla", "arena"):
-            raise ValueError(f"memory must be 'xla' or 'arena', got {memory!r}")
+    def __init__(self, compiled: "CompiledModel"):
         self.compiled = compiled
-        self.memory = memory
-        self.donate_inputs = bool(donate_inputs)
-        self.staging = bool(staging)
         self._entries: dict[tuple, AotEntry] = {}
         self._lock = threading.Lock()
         self._dispatch_overhead: dict | None = None
-        # static accounting: cross-module boundaries in execution order,
-        # mirroring the pipeline scheduler's transfer-at-consumer-start
-        # derivation — with double buffering, boundary k's input DMA can
-        # overlap boundary k-1's producing compute.
-        segs = compiled.mapped.segments
-        self._boundaries: list[dict] = []
-        for i in range(len(segs) - 1):
-            a, b = segs[i], segs[i + 1]
-            if a.module != b.module:
-                self._boundaries.append(
-                    {
-                        "producer": a.anchor.name,
-                        "consumer": b.anchor.name,
-                        "modules": [a.module, b.module],
-                        "tensor": a.output_node.name,
-                        "slot": len(self._boundaries) % 2,
-                        "transfer_cycles": b.transfer_cycles,
-                        "overlap_cycles": min(b.transfer_cycles, a.cycles),
-                    }
-                )
 
     # -- introspection ---------------------------------------------------
     @property
@@ -201,11 +135,6 @@ class AotModel:
     @property
     def target(self):
         return self.compiled.target
-
-    def predicted_overlap_cycles(self) -> float:
-        """Transfer cycles the double-buffered staging can hide behind the
-        preceding segment's compute (scheduler-consistent accounting)."""
-        return sum(b["overlap_cycles"] for b in self._boundaries)
 
     # -- compilation -----------------------------------------------------
     def _entry_key(self, params: dict, sig: tuple) -> tuple:
@@ -235,22 +164,14 @@ class AotModel:
         abstract = {
             k: jax.ShapeDtypeStruct(v.shape, v.dtype) for k, v in inputs.items()
         }
-        if self.memory == "arena":
-            fn, arena_elems, fallbacks = self._build_arena_fn(params, abstract)
-            jitted = jax.jit(fn, donate_argnums=(0,))
-            args = (jax.ShapeDtypeStruct((arena_elems,), jnp.float32), abstract)
-        else:
-            fn = self._build_xla_fn(params)
-            jitted = jax.jit(fn, donate_argnums=(0,) if self.donate_inputs else ())
-            arena_elems, fallbacks = 0, ()
-            args = (abstract,)
+        jitted = jax.jit(self._build_xla_fn(params))
         with obs.span(
             "aot.compile", cat="compile", graph=self.graph.name,
-            target=self.target.name, memory=self.memory,
+            target=self.target.name,
         ) as sp:
             t0 = time.perf_counter()
             try:
-                lowered = jitted.lower(*args)
+                lowered = jitted.lower(abstract)
             except Exception as e:
                 raise AotCompileError(
                     f"whole-graph trace failed for {self.graph.name} on "
@@ -259,25 +180,14 @@ class AotModel:
             t1 = time.perf_counter()
             executable = lowered.compile()
             t2 = time.perf_counter()
-            sp.set(
-                trace_us=(t1 - t0) * 1e6,
-                compile_us=(t2 - t1) * 1e6,
-                arena_fallbacks=list(fallbacks),
-            )
-        if fallbacks:
-            obs.counter("aot.arena_fallbacks").inc(len(fallbacks))
-        entry = AotEntry(
+            sp.set(trace_us=(t1 - t0) * 1e6, compile_us=(t2 - t1) * 1e6)
+        return AotEntry(
             signature=sig,
             executable=executable,
             trace_us=(t1 - t0) * 1e6,
             compile_us=(t2 - t1) * 1e6,
             params=params,
-            arena_elems=arena_elems,
-            arena_fallbacks=tuple(fallbacks),
         )
-        if self.memory == "arena":
-            entry.arena = jnp.zeros((arena_elems,), jnp.float32)
-        return entry
 
     def _build_xla_fn(self, params: dict) -> Callable:
         """Whole program with SSA intermediates: segments inlined in
@@ -295,109 +205,6 @@ class AotModel:
 
         return whole
 
-    def _build_arena_fn(self, params: dict, abstract: dict):
-        """Whole program threading the planned arena: every buffer at its
-        first-fit/hill-climb offset, cross-module boundary tensors staged
-        through two alternating double-buffer slots."""
-        compiled = self.compiled
-        graph = self.graph
-        segments = compiled.segments
-        view = compiled.memory_plan.arena_view()
-
-        # abstract shape pass: segment output shapes/dtypes before any
-        # arena layout decision (slot sizing needs them)
-        shapes: dict[str, jax.ShapeDtypeStruct] = dict(abstract)
-        for ls in segments:
-            xs = [shapes[nm] for nm in ls.input_names]
-            # bind the concrete params via partial: eval_shape abstracts
-            # its *arguments*, and e.g. the Pallas requant shift must stay
-            # a concrete (static) value during the shape pass too
-            shapes[ls.output_name] = jax.eval_shape(
-                partial(ls.fn, ls.params_slice(params)), *xs
-            )
-
-        def elems(name: str) -> int:
-            return int(np.prod(shapes[name].shape)) if shapes[name].shape else 1
-
-        # planned placement; a tensor larger than its planned slot (the
-        # plan sized it in declared elem_bytes) falls back to SSA
-        place: dict[str, int] = {}
-        fallbacks: list[str] = []
-        for name in shapes:
-            off = view.offsets.get(name)
-            if off is None:
-                continue
-            if elems(name) <= view.capacities_elems.get(name, 0):
-                place[name] = off
-            else:
-                fallbacks.append(name)
-
-        # double-buffer staging slots for cross-module boundary tensors
-        # whose only consumer is the next segment (classic handoff shape)
-        consumers_of: dict[str, set[int]] = {}
-        for i, ls in enumerate(segments):
-            for nm in ls.input_names:
-                consumers_of.setdefault(nm, set()).add(i)
-        staged: dict[str, int] = {}
-        if self.staging:
-            for b in self._boundaries:
-                t = b["tensor"]
-                cons = consumers_of.get(t, set())
-                nxt = next(
-                    i for i, ls in enumerate(segments) if ls.name == b["consumer"]
-                )
-                if t in place and cons == {nxt} and t not in graph.outputs:
-                    staged[t] = b["slot"]
-        slot_elems = [0, 0]
-        for t, s in staged.items():
-            slot_elems[s] = max(slot_elems[s], elems(t))
-        slot_off = [
-            view.length_elems,
-            view.length_elems + slot_elems[0],
-        ]
-        arena_elems = max(1, view.length_elems + slot_elems[0] + slot_elems[1])
-
-        def offset_of(name: str) -> int | None:
-            if name in staged:
-                return slot_off[staged[name]]
-            return place.get(name)
-
-        outputs = graph.outputs
-
-        def whole(arena, inputs):
-            ssa: dict[str, jnp.ndarray] = {}
-
-            def store(arena, name, val):
-                off = offset_of(name)
-                if off is None:
-                    ssa[name] = val
-                    return arena
-                flat = val.astype(jnp.float32).reshape(-1)
-                scope = (
-                    f"dma_stage{staged[name]}" if name in staged else "arena_store"
-                )
-                with jax.named_scope(scope):
-                    return jax.lax.dynamic_update_slice(arena, flat, (off,))
-
-            def load(arena, name):
-                off = offset_of(name)
-                if off is None:
-                    return ssa[name]
-                sd = shapes[name]
-                flat = jax.lax.dynamic_slice(arena, (off,), (elems(name),))
-                return flat.reshape(sd.shape).astype(sd.dtype)
-
-            for name in inputs:
-                arena = store(arena, name, inputs[name])
-            for ls in segments:
-                xs = [load(arena, nm) for nm in ls.input_names]
-                with jax.named_scope(f"seg{ls.index}.{ls.module}"):
-                    out = ls.fn(ls.params_slice(params), *xs)
-                arena = store(arena, ls.output_name, out)
-            return {o: load(arena, o) for o in outputs}, arena
-
-        return whole, arena_elems, fallbacks
-
     # -- execution -------------------------------------------------------
     def run(self, params: dict, inputs: dict) -> dict:
         """Execute the whole graph in one XLA dispatch.
@@ -413,27 +220,13 @@ class AotModel:
         if tr.enabled:
             with tr.span("aot.coerce"):
                 coerced = {k: _as_input(v) for k, v in inputs.items()}
-            with tr.span("aot.dispatch", graph=self.graph.name, memory=self.memory):
+            with tr.span("aot.dispatch", graph=self.graph.name):
                 return self._dispatch(params, coerced)
         return self._dispatch(params, {k: _as_input(v) for k, v in inputs.items()})
 
     def _dispatch(self, params: dict, coerced: dict) -> dict:
         entry = self.warmup(params, coerced)
         entry.calls += 1
-        return self._run_entry(entry, coerced)
-
-    def _run_entry(self, entry: "AotEntry", coerced: dict) -> dict:
-        if self.memory == "arena":
-            with self._lock:  # the donated arena is single-owner state
-                arena = entry.arena
-                out, new_arena = entry.executable(arena, coerced)
-                if entry.donation_honored is None:
-                    try:
-                        entry.donation_honored = bool(arena.is_deleted())
-                    except Exception:  # pragma: no cover
-                        entry.donation_honored = None
-                entry.arena = new_arena
-            return dict(out)
         return dict(entry.executable(coerced))
 
     def verify(self, params: dict, inputs: dict) -> float:
@@ -478,157 +271,31 @@ class AotModel:
         return dict(self._dispatch_overhead)
 
     def stats(self) -> dict:
-        """JSON-safe AOT report: trace/compile cost, executable size,
-        donation coverage, staging accounting, measured dispatch
-        overhead (the ``report_dict()["aot"]`` payload)."""
+        """JSON-safe AOT report: trace/compile cost, executable size, the
+        plan's aliasing, measured dispatch overhead (the
+        ``report_dict()["aot"]`` payload).  ``intermediate_share`` is the
+        share of the plan's bytes held by intermediates, which never leave
+        the executable: XLA's buffer assignment owns them."""
         plan = self.compiled.memory_plan
         io_names = set(self.graph.inputs) | set(self.graph.outputs)
         total = sum(b.nbytes for b in plan.buffers.values())
         internal = sum(
             b.nbytes for n, b in plan.buffers.items() if n not in io_names
         )
-        if self.memory == "arena":
-            entries = list(self._entries.values())
-            fell_back = {n for e in entries for n in e.arena_fallbacks}
-            covered = sum(
-                b.nbytes for n, b in plan.buffers.items() if n not in fell_back
-            )
-            donation = {
-                "mode": "arena",
-                "plan_bytes": total,
-                "covered_bytes": covered,
-                "coverage": covered / max(total, 1),
-                "arena_donation_honored": next(
-                    (e.donation_honored for e in entries if e.donation_honored is not None),
-                    None,
-                ),
-                "fallback_buffers": sorted(fell_back),
-            }
-        else:
-            donation = {
-                "mode": "xla",
-                "plan_bytes": total,
-                # intermediates never leave the executable: XLA's buffer
-                # assignment owns them (the aliasing the plan decided is
-                # re-derived inside XLA instead of imposed)
-                "covered_bytes": internal,
-                "coverage": internal / max(total, 1),
-                "inputs_donated": self.donate_inputs,
-                "fallback_buffers": sorted(io_names & set(plan.buffers)),
-            }
         return {
-            "mode": self.memory,
             "segments": len(self.compiled.segments),
-            "staging": {
-                "enabled": self.staging,
-                "slots": 2,
-                "boundaries": [dict(b) for b in self._boundaries],
-                "predicted_overlap_cycles": self.predicted_overlap_cycles(),
-            },
-            "donation": donation,
+            "intermediate_share": internal / max(total, 1),
             "plan_aliasing": plan.aliasing_summary(),
             "entries": [e.to_dict() for e in self._entries.values()],
             "dispatch_overhead": self._dispatch_overhead,
         }
 
 
-def compile_aot(
-    compiled: "CompiledModel",
-    *,
-    memory: str = "xla",
-    donate_inputs: bool = False,
-    staging: bool = True,
-) -> AotModel:
+def compile_aot(compiled: "CompiledModel") -> AotModel:
     """Fuse a :class:`CompiledModel` into one whole-graph AOT executable.
 
     The returned :class:`AotModel` traces lazily: the XLA compile happens
     on :meth:`AotModel.warmup` (or the first :meth:`AotModel.run`) for
-    each (params, input shapes/dtypes) signature and is cached.  See the
-    module docstring for the ``memory`` / donation semantics.
+    each (params, input shapes/dtypes) signature and is cached.
     """
-    return AotModel(
-        compiled, memory=memory, donate_inputs=donate_inputs, staging=staging
-    )
-
-
-# ---------------------------------------------------------------------------
-# Lane chaining: the PipelinedModel AOT fast path
-# ---------------------------------------------------------------------------
-
-
-@dataclass
-class ChainExecutor:
-    """One jitted executor for a dependency-closed run of lane segments.
-
-    ``fn(*xs)`` takes the chain's external inputs (first-use order) and
-    returns one output per member segment, so the pipelined worker
-    resolves every member's future from a single dispatch — fewer future
-    hops and fewer host round-trips per input.
-    """
-
-    segments: tuple["LoweredSegment", ...]
-    ext_inputs: tuple[str, ...]
-    fn: Callable
-
-    @property
-    def output_names(self) -> tuple[str, ...]:
-        return tuple(ls.output_name for ls in self.segments)
-
-
-def build_chains(
-    lane: Sequence["LoweredSegment"], graph_inputs: Sequence[str]
-) -> list[list["LoweredSegment"]]:
-    """Group a module lane into maximal dependency-closed runs.
-
-    A segment joins the current chain when every one of its external
-    inputs is either a graph input (resolved before the stream starts)
-    or produced by an earlier member of the same chain — i.e. collapsing
-    the run into one dispatch never has to *wait* mid-chain on another
-    lane's future.  Anything else starts a new chain.
-    """
-    always = set(graph_inputs)
-    chains: list[list["LoweredSegment"]] = []
-    for ls in lane:
-        if chains:
-            produced = {c.output_name for c in chains[-1]}
-            if all(nm in produced or nm in always for nm in ls.input_names):
-                chains[-1].append(ls)
-                continue
-        chains.append([ls])
-    return chains
-
-
-def make_chain_executor(
-    chain: Sequence["LoweredSegment"], params: dict
-) -> ChainExecutor:
-    """Compile one chain into a single jitted callable (params baked as
-    constants, same contract as :class:`AotModel`).  Singleton chains
-    reuse the segment's own executor unwrapped — no extra trace."""
-    chain = tuple(chain)
-    internal = {ls.output_name for ls in chain}
-    ext: list[str] = []
-    for ls in chain:
-        for nm in ls.input_names:
-            if nm not in internal and nm not in ext:
-                ext.append(nm)
-    ext_t = tuple(ext)
-    if len(chain) == 1:
-        ls0 = chain[0]
-        sp0 = ls0.params_slice(params)
-
-        def single(*xs):
-            env = dict(zip(ext_t, xs))
-            return (ls0.fn(sp0, *[env[nm] for nm in ls0.input_names]),)
-
-        return ChainExecutor(chain, ext_t, single)
-
-    seg_params = [ls.params_slice(params) for ls in chain]
-
-    @jax.jit
-    def fused(*xs):
-        env = dict(zip(ext_t, xs))
-        for ls, sp in zip(chain, seg_params):
-            env[ls.output_name] = ls.fn(sp, *[env[nm] for nm in ls.input_names])
-        return tuple(env[ls.output_name] for ls in chain)
-
-    return ChainExecutor(chain, ext_t, fused)
+    return AotModel(compiled)

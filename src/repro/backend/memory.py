@@ -31,7 +31,6 @@ from repro import obs
 from repro.core import MappedGraph, tile_working_set
 
 __all__ = [
-    "ArenaView",
     "BufferAlloc",
     "MemoryPlan",
     "MemoryPlanError",
@@ -64,31 +63,6 @@ class BufferAlloc:
             self.offset + self.nbytes <= other.offset
             or other.offset + other.nbytes <= self.offset
         )
-
-
-@dataclass(frozen=True)
-class ArenaView:
-    """The home-level byte arena re-addressed for a fixed-width runtime.
-
-    The plan's offsets are byte-addressed with each buffer's declared
-    ``elem_bytes``; the jax host runtime materializes every tensor at a
-    uniform ``elem_bytes`` (float32 = 4).  Scaling *every* byte
-    coordinate by that width — i.e. reading each planned byte offset as
-    an element offset — preserves the first-fit/hill-climb layout and
-    the pairwise-disjointness proof verbatim: buffer b's byte interval
-    ``[off, off+nbytes)`` becomes the element interval of the same
-    numbers, and a tensor of ``nbytes / declared_width`` elements always
-    fits inside it because declared widths are >= 1 byte.  The cost is
-    up to ``elem_bytes``x the modeled footprint, paid in *host* memory
-    only — the byte plan (what deployment validates against the declared
-    capacities) is untouched.
-    """
-
-    home_level: str
-    length_elems: int  # arena length, in runtime elements
-    elem_bytes: int
-    offsets: dict[str, int]  # buffer -> element offset (== planned byte offset)
-    capacities_elems: dict[str, int]  # buffer -> element capacity (== nbytes)
 
 
 @dataclass
@@ -138,26 +112,12 @@ class MemoryPlan:
                     return False
         return True
 
-    def arena_view(self, elem_bytes: int = 4) -> ArenaView:
-        """The plan's home arena re-addressed for a uniform-width runtime
-        (see :class:`ArenaView`) — what the whole-graph AOT executor
-        (``repro.backend.aot``, ``memory="arena"``) threads through the
-        jitted program so the first-fit/hill-climb offsets survive into
-        the executable instead of being re-derived by XLA."""
-        return ArenaView(
-            home_level=self.home_level,
-            length_elems=self.arena_bytes.get(self.home_level, 0),
-            elem_bytes=int(elem_bytes),
-            offsets={n: b.offset for n, b in self.buffers.items()},
-            capacities_elems={n: b.nbytes for n, b in self.buffers.items()},
-        )
-
     def aliasing_summary(self) -> dict:
         """The plan's buffer-aliasing decisions, summarized: how many
         buffer pairs share home-arena bytes (lifetimes disjoint, offsets
         overlapping) and how many bytes that reuse saves over a
-        no-aliasing layout — the number the AOT donation-coverage report
-        compares XLA's own buffer assignment against."""
+        no-aliasing layout — what ``AotModel.stats()`` reports beside the
+        executable, whose buffers XLA's own assignment decides."""
         allocs = list(self.buffers.values())
         pairs = 0
         for i, a in enumerate(allocs):

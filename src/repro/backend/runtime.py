@@ -344,17 +344,17 @@ class CompiledModel:
         return out
 
     # -- AOT ------------------------------------------------------------
-    def to_aot(self, **kw):
+    def to_aot(self):
         """The whole-graph one-jit AOT executor for this model
         (:func:`repro.backend.aot.compile_aot`): all segments fused into
         a single XLA program, bit-exact with :meth:`run` by construction.
-        Cached — repeated calls with no overrides return the same
+        Built once — repeated calls return the same
         :class:`~repro.backend.aot.AotModel`, whose stats then ship in
         ``report_dict()["aot"]``."""
         from .aot import compile_aot  # no cycle: late import
 
-        if self._aot is None or kw:
-            self._aot = compile_aot(self, **kw)
+        if self._aot is None:
+            self._aot = compile_aot(self)
         return self._aot
 
     def pipeline_schedule(self):
@@ -460,7 +460,7 @@ class CompiledModel:
             },
         }
         if self._aot is not None:
-            # trace/compile cost, executable size, donation coverage and
+            # trace/compile cost, executable size, plan aliasing and
             # measured dispatch overhead of the whole-graph AOT executor
             out["aot"] = self._aot.stats()
         if measured:

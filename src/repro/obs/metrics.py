@@ -3,10 +3,9 @@
 Counters, gauges and histograms that every subsystem increments as it
 works — DSE query volume and schedule-cache hit rates from the
 dispatcher, lowering-route tallies, memory-planner spills, AOT
-executable-cache hits and donation fallbacks, per-segment latency
-histograms from timed runs.  :func:`metrics_dict` snapshots the whole
-registry as plain JSON-safe data; ``CompiledModel.report_dict()["obs"]``
-embeds it so a single report answers "which cache missed".
+executable-cache hits, per-segment latency histograms from timed runs.
+:func:`metrics_dict` snapshots the whole registry as plain JSON-safe data;
+``CompiledModel.report_dict()["obs"]`` embeds it so a single report answers "which cache missed".
 
 Unlike the tracer there is no off switch: a counter bump is one dict
 lookup + integer add, far below measurement noise, and having the

@@ -50,18 +50,6 @@ class PipelinedModel:
     silently running an undeployable configuration — the single-input
     plan at construction, the streaming plan on the first ``run_stream``
     call (plain ``run()`` never touches the queue copies).
-
-    ``aot=True`` turns on the AOT fast path: each module lane's
-    dependency-closed runs of consecutive segments collapse into one
-    jitted chain (:func:`repro.backend.aot.build_chains`), so a worker
-    resolves one future *per chain* instead of one per segment — fewer
-    host dispatches and future hops per input, which is where
-    ``run_stream`` throughput went on sub-millisecond nets.  Chain
-    executors bake params as constants (the AotModel contract) and are
-    cached per params dict.  Buffer lifetimes, lane order and
-    happens-before are unchanged: every segment output is still
-    materialized and published, so the overlap-aware memory plan applies
-    as-is and bit-exactness is inherited from the segment bodies.
     """
 
     def __init__(
@@ -72,7 +60,6 @@ class PipelinedModel:
         stream_depth: int = 2,
         validate_memory: bool = True,
         timeout_s: float = 600.0,
-        aot: bool = False,
     ):
         from repro.backend.memory import plan_memory
 
@@ -114,18 +101,6 @@ class PipelinedModel:
         if self._validate_memory:
             self.memory_plan.validate()
         self._streaming_plan = None
-        self.aot = bool(aot)
-        self._chain_lanes: dict[str, list] = {}
-        if self.aot:
-            from repro.backend.aot import build_chains
-
-            graph_inputs = set(compiled.graph.inputs)
-            for module, lane in self._lanes.items():
-                self._chain_lanes[module] = build_chains(lane, graph_inputs)
-        # chain executors bake params as jit constants, so they are cached
-        # per params dict (strong ref keeps id() stable for the entry's life)
-        self._chain_cache: dict[int, tuple[dict, dict[str, list]]] = {}
-        self._chain_lock = threading.Lock()
 
     # -- introspection ---------------------------------------------------
     @property
@@ -194,27 +169,6 @@ class PipelinedModel:
             self.streaming_plan()  # reserve + validate the queue copies
         return self._execute(params, list(inputs), depth=d)
 
-    def _executors_for(self, params: dict) -> dict[str, list]:
-        """Per-module chain executors for this params dict (aot mode).
-
-        Built lazily on first use and memoized by ``id(params)`` — the
-        executors close over the concrete param arrays as jit constants,
-        mirroring :class:`repro.backend.aot.AotModel`'s entry cache.
-        """
-        from repro.backend.aot import make_chain_executor
-
-        key = id(params)
-        with self._chain_lock:
-            hit = self._chain_cache.get(key)
-            if hit is not None and hit[0] is params:
-                return hit[1]
-            execs = {
-                module: [make_chain_executor(chain, params) for chain in chains]
-                for module, chains in self._chain_lanes.items()
-            }
-            self._chain_cache[key] = (params, execs)
-            return execs
-
     def _execute(self, params: dict, inputs_list: list[dict], *, depth: int) -> list[dict]:
         from repro.backend.runtime import as_input_array
 
@@ -231,26 +185,10 @@ class PipelinedModel:
             for ls in self.compiled.segments:
                 futs[(k, ls.output_name)] = Future()
 
-        # a worker walks "steps": (input names, output names, call).  The
-        # default path is one step per segment — today's exact behaviour.
-        # The aot path is one step per collapsed chain: a single jitted
-        # dispatch resolves every member segment's future at once.
-        steps: dict[str, list[tuple[tuple[str, ...], tuple[str, ...], object]]] = {}
-        if self.aot:
-            for module, execs in self._executors_for(params).items():
-                steps[module] = [(ce.ext_inputs, ce.output_names, ce.fn) for ce in execs]
-        else:
-            for module, lane in self._lanes.items():
-                steps[module] = [
-                    (
-                        tuple(ls.input_names),
-                        (ls.output_name,),
-                        (lambda sp, f: lambda *xs: (f(sp, *xs),))(
-                            ls.params_slice(params), ls.fn
-                        ),
-                    )
-                    for ls in lane
-                ]
+        steps = {
+            module: [(ls, ls.params_slice(params)) for ls in lane]
+            for module, lane in self._lanes.items()
+        }
 
         # admission gate: input k may enter the pipeline only once input
         # k-depth has been fully collected (bounds live queue copies to
@@ -265,35 +203,30 @@ class PipelinedModel:
 
         tracer = obs.get_tracer()
 
-        def worker(
-            module: str,
-            lane_steps: list[tuple[tuple[str, ...], tuple[str, ...], object]],
-        ) -> None:
+        def worker(module: str, lane_steps: list) -> None:
             tracing = tracer.enabled
             for k in range(n_inputs):
                 admitted = admit[k].wait(timeout)
-                for ext_inputs, out_names, call in lane_steps:
-                    out_futs = [futs[(k, nm)] for nm in out_names]
+                for ls, seg_params in lane_steps:
+                    out_fut = futs[(k, ls.output_name)]
                     if stop.is_set() or not admitted:
-                        err = RuntimeError(
+                        out_fut.set_exception(RuntimeError(
                             "pipeline cancelled"
                             if stop.is_set()
                             else f"input {k} was never admitted within "
                             f"{timeout}s (pipeline stalled upstream)"
-                        )
-                        for of in out_futs:
-                            of.set_exception(err)
+                        ))
                         continue
                     try:
-                        xs = [futs[(k, nm)].result(timeout) for nm in ext_inputs]
+                        xs = [futs[(k, nm)].result(timeout) for nm in ls.input_names]
                         if tracing:
                             # block so the span covers the compute, not
                             # just the async dispatch; untraced runs keep
                             # jax's pipelined dispatch untouched
                             t0_us = tracer.now_us()
-                            outs = jax.block_until_ready(call(*xs))
+                            out = jax.block_until_ready(ls.fn(seg_params, *xs))
                             tracer.complete(
-                                f"{out_names[0]}@{k}", t0_us, cat="runtime",
+                                f"{ls.output_name}@{k}", t0_us, cat="runtime",
                                 lane=f"pipeline:{module}",
                                 attrs={
                                     "input": k,
@@ -301,13 +234,11 @@ class PipelinedModel:
                                 },
                             )
                         else:
-                            outs = call(*xs)
+                            out = ls.fn(seg_params, *xs)
                     except BaseException as e:  # propagate through the DAG
-                        for of in out_futs:
-                            of.set_exception(e)
+                        out_fut.set_exception(e)
                     else:
-                        for of, out in zip(out_futs, outs):
-                            of.set_result(out)
+                        out_fut.set_result(out)
 
         threads = [
             threading.Thread(target=worker, args=(m, lane), daemon=True, name=f"pipeline-{m}")

@@ -13,9 +13,9 @@ each starts at ``max(module_free[its module], latest dependency
 finish)``.  Two properties follow, both load-bearing for the tests:
 
 * **Degenerate exactness** — when every segment lands on one module the
-  schedule serialises and the makespan accumulates ``seg.total_cycles``
-  in dispatch order, reproducing ``MappedGraph.total_cycles()`` bit for
-  bit (same float additions in the same order).
+  schedule serialises into one busy run from 0, and the makespan sums
+  ``seg.total_cycles`` in dispatch order with ``sum()``, reproducing
+  ``MappedGraph.total_cycles()`` bit for bit (the same float sum).
 * **Never worse than sequential** — by induction every segment finishes
   no later than it would in the sequential schedule, so
   ``makespan <= total_cycles()`` for every mapping.
@@ -242,6 +242,12 @@ def schedule_pipeline(mapped: MappedGraph) -> PipelineSchedule:
     finish: list[float] = [0.0] * len(segments)
     module_free: dict[str, float] = {}
     module_last: dict[str, int] = {}
+    # per module, the busy run the lane is in: its start and the cycles
+    # of its segments so far.  A run's finish is its start plus sum() of
+    # those cycles, the same sum() total_cycles() takes, so a one-module
+    # cover (one run from 0.0) reproduces total_cycles() bit for bit
+    run_start: dict[str, float] = {}
+    run_cycles: dict[str, list[float]] = {}
     entries: list[ScheduledSegment] = []
     for i, seg in enumerate(segments):
         ready = 0.0
@@ -255,9 +261,11 @@ def schedule_pipeline(mapped: MappedGraph) -> PipelineSchedule:
                 ready = finish[d]
                 blocker = d
         start = ready
-        # one accumulation per segment, in dispatch order — the exact
-        # float sum total_cycles() computes in the single-module case
-        fin = start + seg.total_cycles
+        if prev is None or blocker != prev:  # the lane idled: a new run
+            run_start[seg.module] = start
+            run_cycles[seg.module] = []
+        run_cycles[seg.module].append(seg.total_cycles)
+        fin = run_start[seg.module] + sum(run_cycles[seg.module])
         finish[i] = fin
         module_free[seg.module] = fin
         module_last[seg.module] = i
@@ -309,7 +317,9 @@ def schedule_stream(
     quantity ``dispatch(..., objective="wct")`` re-ranks segmentations
     by), and ``request_order`` (the lane order chosen).  With one
     unit-weight request this reproduces :func:`schedule_pipeline`'s
-    makespan bit for bit (same float accumulations in the same order).
+    schedule.  Its finishes add one segment at a time, so on a long busy
+    run they can differ from :func:`schedule_pipeline`'s ``sum()`` in the
+    last bits.
     """
     if order not in ("smith", "fifo"):
         raise ValueError(f"unknown stream order {order!r} (smith | fifo)")

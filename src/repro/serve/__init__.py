@@ -1,7 +1,7 @@
-"""repro.serve — request-level serving over the compiled pipeline.
+"""repro.serve — request-level serving over a compiled model.
 
-PR 5 made one *input stream* fast; this package makes many *users*
-fast.  A :class:`ModelServer` replica fronts a ``CompiledModel`` with:
+A :class:`ModelServer` replica serves many users over one
+``CompiledModel`` with:
 
 * :class:`AdmissionQueue` — a bounded priority queue (reject /
   backpressure policies) so heavy traffic sheds at the door instead of

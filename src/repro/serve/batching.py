@@ -7,24 +7,18 @@ inputs along a leading *slot* axis and mapping every
 the unbatched ones, so the winning LOMA tiles, the fused epilogues and
 the memory plan all apply unchanged, and per-request outputs stay
 bit-exact with running ``CompiledModel.run`` one request at a time
-(held by tests/test_serve.py and the serve_load benchmark gate).
+(held by tests/test_serve.py and, on every target and MLPerf-Tiny net, by
+tests/conformance/test_batched.py).
 
-Two execution surfaces:
-
-* :meth:`BatchedModel.batched_segments` — vmapped per-segment executors
-  (same ``LoweredSegment`` dataclass, batched ``fn``), which is what a
-  batched :class:`~repro.pipeline.runtime.PipelinedModel` runs for
-  module-concurrent streaming;
-* :meth:`BatchedModel.run_batch` — the whole batched graph fused into
-  ONE AOT-compiled executable per batch shape (the PR 6 follow-up:
-  ``jax.jit(...).lower().compile()`` with params baked as constants,
-  cached per ``(params identity, stacked input signature)``), so a
-  steady-state replica pays one host dispatch per batch of users.
+:meth:`BatchedModel.run_batch` fuses the whole batched graph into ONE
+AOT-compiled executable per batch shape (``jax.jit(...).lower().compile()``
+with params baked as constants, cached per ``(params identity, stacked
+input signature)``), so a steady-state replica pays one host dispatch per
+batch of users.
 """
 
 from __future__ import annotations
 
-import dataclasses
 import threading
 import time
 from typing import TYPE_CHECKING, Sequence
@@ -35,7 +29,6 @@ import numpy as np
 from repro import obs
 
 if TYPE_CHECKING:  # repro.backend stays import-light; duck-typed at runtime
-    from repro.backend.lower import LoweredSegment
     from repro.backend.runtime import CompiledModel
 
 __all__ = ["BatchedModel"]
@@ -76,33 +69,15 @@ class BatchedModel:
 
     def __init__(self, compiled: "CompiledModel"):
         self.compiled = compiled
-        self._batched_segments: list["LoweredSegment"] | None = None
         # (params id, input signature) -> (params ref, compiled executable,
-        # stats row); the strong params ref keeps id() stable, mirroring
-        # PipelinedModel._chain_cache
+        # stats row); the strong params ref keeps id() stable while the
+        # entry lives
         self._entries: dict[tuple, tuple[dict, object, dict]] = {}
         self._lock = threading.Lock()
 
     @property
     def graph(self):
         return self.compiled.graph
-
-    # -- vmapped per-segment executors ----------------------------------
-    def batched_segments(self) -> list["LoweredSegment"]:
-        """Per-segment executors accepting ``(B, ...)``-stacked operands.
-
-        Params stay unbatched (``in_axes`` None): every slot shares the
-        one model, exactly like rows of a serving batch share weights.
-        """
-        if self._batched_segments is None:
-            segs = []
-            for ls in self.compiled.segments:
-                vfn = jax.vmap(
-                    ls.fn, in_axes=(None,) + (0,) * len(ls.input_names)
-                )
-                segs.append(dataclasses.replace(ls, fn=vfn))
-            self._batched_segments = segs
-        return self._batched_segments
 
     # -- stacking -------------------------------------------------------
     def stack(self, inputs_list: Sequence[dict]) -> dict:
@@ -166,15 +141,19 @@ class BatchedModel:
             if hit is not None and hit[0] is params:
                 obs.counter("serve.entry_hits").inc()
                 return hit[1]
-        segs = self.batched_segments()
+        segs = self.compiled.segments
         outputs = self.graph.outputs
         input_names = tuple(self.graph.inputs.keys())
 
         def whole_batch(batch_inputs: dict) -> dict:
+            # every segment executor vmapped over the slot axis; params
+            # stay unbatched (in_axes None): all slots share one model
             env = dict(batch_inputs)
             for ls in segs:
                 with jax.named_scope(f"seg{ls.index}.{ls.module}"):
-                    env[ls.output_name] = ls.fn(
+                    env[ls.output_name] = jax.vmap(
+                        ls.fn, in_axes=(None,) + (0,) * len(ls.input_names)
+                    )(
                         ls.params_slice(params),
                         *[env[nm] for nm in ls.input_names],
                     )

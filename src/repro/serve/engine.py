@@ -1,8 +1,6 @@
-"""ModelServer: request-level serving over a compiled pipeline.
+"""ModelServer: request-level serving over a compiled model.
 
-Fuses the two halves of ROADMAP item 1 — the seed slot-batching idea
-from ``repro.serving`` and PR 5's software-pipelined runtime — into one
-replica loop:
+One replica loop over the packed-batch executor:
 
 * an :class:`~repro.serve.queue.AdmissionQueue` bounds waiting work
   (reject/backpressure) and pops in Smith's-rule priority order; a round
@@ -15,11 +13,9 @@ replica loop:
 * :class:`~repro.serve.batching.BatchedModel` packs up to
   ``batch_slots`` requests into one vmapped execution, with one
   AOT-compiled executable per batch shape;
-* batches flow through an in-flight window of ``stream_depth`` —
-  literally :meth:`PipelinedModel.run_stream` in ``mode="pipeline"``
-  (one worker thread per execution module, admission events bounding
-  in-flight inputs), or ``stream_depth`` asynchronously dispatched AOT
-  batches in ``mode="aot"`` (the fastest host path);
+* batches flow through an in-flight window of ``stream_depth``
+  asynchronously dispatched AOT batches, each padded to ``batch_slots``
+  rows so one executable serves every round;
 * every request gets a span on the ``serve:<replica>`` trace lane and
   feeds the ``serve.*`` metrics (`completed`, `deadline_misses`; the
   queue keeps `queue_depth`, `rejected`) that ship in
@@ -91,11 +87,8 @@ class ModelServer:
 
     ``batch_slots`` requests share one vmapped execution;
     ``stream_depth`` batches may be in flight at once; ``queue_capacity``
-    + ``policy`` ("reject" | "block") set the admission valve.
-    ``mode="aot"`` (default) runs one AOT batch executable per round
-    entry; ``mode="pipeline"`` runs batches through a batched
-    :class:`~repro.pipeline.runtime.PipelinedModel.run_stream` so
-    execution modules overlap *within* each batch too.
+    + ``policy`` ("reject" | "block") set the admission valve.  Each
+    batch runs as one AOT batch executable, padded to ``batch_slots``.
 
     ``slo`` takes :class:`repro.obs.SloSpec` objectives evaluated once
     per round over a ``slo_window_s`` rolling window (breach transitions
@@ -113,9 +106,7 @@ class ModelServer:
         stream_depth: int = 2,
         queue_capacity: int = 64,
         policy: str = "reject",
-        mode: str = "aot",
         replica: str = "r0",
-        pad_to_slots: bool = True,
         timeout_s: float = 600.0,
         slo=None,
         slo_window_s: float = 60.0,
@@ -126,25 +117,17 @@ class ModelServer:
             raise ValueError(f"batch_slots must be >= 1, got {batch_slots}")
         if stream_depth < 1:
             raise ValueError(f"stream_depth must be >= 1, got {stream_depth}")
-        if mode not in ("aot", "pipeline"):
-            raise ValueError(f"unknown serve mode {mode!r} (aot | pipeline)")
         self.compiled = compiled
         self.params = params
         self.batch_slots = int(batch_slots)
         self.stream_depth = int(stream_depth)
-        self.mode = mode
         self.replica = replica
-        # pad partial groups to batch_slots (rows repeat the last
-        # request): every batch then shares ONE AOT entry shape, trading
-        # a little wasted vmap compute for zero mid-load recompiles
-        self.pad_to_slots = bool(pad_to_slots)
         self.timeout_s = float(timeout_s)
         self.batched = BatchedModel(compiled)
         self.queue = AdmissionQueue(queue_capacity, policy)
         self._rids = itertools.count()
         self._thread: threading.Thread | None = None
         self._start_lock = threading.Lock()
-        self._pipelined = None
         # per-replica aggregates (the process-wide serve.* metrics are
         # shared across replicas; stats() must stay attributable)
         self._submitted = 0
@@ -216,15 +199,10 @@ class ModelServer:
         self.compiled.attrs["serve"] = self.stats()
 
     def warmup(self, example_inputs: dict) -> "ModelServer":
-        """Trace + compile the full-batch AOT entry (and the pipeline
-        clone's jit chains) before load arrives, so the first round pays
-        no compilation.  ``example_inputs`` is one request's input dict;
-        the result is discarded."""
-        batch = [example_inputs] * self.batch_slots
-        if self.mode == "pipeline":
-            self._pipelined_model().run(self.params, self.batched.stack(batch))
-        else:
-            self.batched.run_batch(self.params, batch)
+        """Trace + compile the full-batch AOT entry before load arrives,
+        so the first round pays no compilation.  ``example_inputs`` is
+        one request's input dict; the result is discarded."""
+        self.batched.run_batch(self.params, [example_inputs] * self.batch_slots)
         return self
 
     def __enter__(self) -> "ModelServer":
@@ -321,10 +299,7 @@ class ModelServer:
             for i in range(0, len(reqs), self.batch_slots)
         ]
         self._batches += len(groups)
-        if self.mode == "pipeline":
-            self._serve_pipelined(groups)
-        else:
-            self._serve_aot(groups)
+        self._serve_aot(groups)
         self._finish_round()
 
     def _shed_expired(self, reqs: list[ServeRequest]) -> list[ServeRequest]:
@@ -390,43 +365,11 @@ class ModelServer:
             self._finish(*inflight.popleft())
 
     def _padded(self, g: list[ServeRequest]) -> list[dict]:
+        """The group's inputs padded to ``batch_slots`` rows (repeating the
+        last request): every batch then shares ONE AOT entry shape, trading
+        a little wasted vmap compute for zero mid-load recompiles."""
         inputs = [r.inputs for r in g]
-        if self.pad_to_slots and len(inputs) < self.batch_slots:
-            inputs = inputs + [inputs[-1]] * (self.batch_slots - len(inputs))
-        return inputs
-
-    def _serve_pipelined(self, groups: list[list[ServeRequest]]) -> None:
-        """Feed stacked batches through ``PipelinedModel.run_stream`` —
-        module-concurrent within a batch, software-pipelined across
-        batches, at most ``stream_depth`` in flight (PR 5 admission)."""
-        pm = self._pipelined_model()
-        stacked = [self.batched.stack(self._padded(g)) for g in groups]
-        outs = pm.run_stream(self.params, stacked)
-        for g, out in zip(groups, outs):
-            self._resolve(g, out)
-
-    def _pipelined_model(self):
-        if self._pipelined is None:
-            import dataclasses
-
-            from repro.pipeline.runtime import PipelinedModel
-
-            # a shallow clone whose executors take (B, ...) operands: the
-            # vmapped fns are batch-size-agnostic, so one PipelinedModel
-            # serves every group size.  Memory validation stays on the
-            # unbatched model — the slot axis multiplies the true
-            # footprint by B, which the single-slot plan does not claim
-            # to bound (stats() records batch_slots for capacity math).
-            clone = dataclasses.replace(
-                self.compiled, segments=self.batched.batched_segments()
-            )
-            self._pipelined = PipelinedModel(
-                clone,
-                stream_depth=self.stream_depth,
-                validate_memory=False,
-                timeout_s=self.timeout_s,
-            )
-        return self._pipelined
+        return inputs + [inputs[-1]] * (self.batch_slots - len(inputs))
 
     def _finish(self, g: list[ServeRequest], outs: dict) -> None:
         with obs.span("serve.block"):
@@ -481,7 +424,6 @@ class ModelServer:
     def _base_stats(self) -> dict:
         return {
             "replica": self.replica,
-            "mode": self.mode,
             "batch_slots": self.batch_slots,
             "stream_depth": self.stream_depth,
             "queue_capacity": self.queue.capacity,

@@ -1,7 +1,6 @@
-"""Unit tests for the whole-graph AOT executor (repro.backend.aot) and
-the PR 6 satellite fixes: input dtype preservation in CompiledModel.run,
-warm-before-sample timed runs, lane chaining for the pipelined AOT fast
-path, and the MemoryPlan arena view the planned-arena program consumes.
+"""Unit tests for the whole-graph AOT executor (repro.backend.aot),
+input dtype preservation in CompiledModel.run, warm-before-sample timed
+runs and the memory plan's aliasing summary.
 """
 
 import time
@@ -11,15 +10,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.backend import (
-    AotModel,
-    build_chains,
-    compile_aot,
-    lower,
-)
-from repro.backend.aot import make_chain_executor
+from repro.backend import compile_aot, lower
 from repro.core import Graph, Node, dispatch
-from repro.pipeline import PipelinedModel
 
 
 # ---------------------------------------------------------------------------
@@ -158,11 +150,6 @@ def test_aot_bit_exact_and_cached(compiled, io):
     assert e3 is not e1
 
 
-def test_aot_rejects_bad_memory_mode(compiled):
-    with pytest.raises(ValueError):
-        AotModel(compiled, memory="paged")
-
-
 def test_to_aot_caches_and_feeds_report_dict(compiled, io):
     params, x = io
     am = compiled.to_aot()
@@ -170,122 +157,17 @@ def test_to_aot_caches_and_feeds_report_dict(compiled, io):
     am.warmup(params, x)
     d = compiled.report_dict()
     assert d["aot"]["segments"] == len(compiled.segments)
-    assert d["aot"]["mode"] == "xla"
-    # rebuild with explicit kwargs replaces the cached model
-    am2 = compiled.to_aot(memory="arena")
-    assert am2 is not am and am2.memory == "arena"
-
-
-def test_aot_arena_mode_survives_donation_swap(compiled, io):
-    params, x = io
-    am = compile_aot(compiled, memory="arena")
-    r1 = am.run(params, x)
-    r2 = am.run(params, x)
-    (a,), (b,) = r1.values(), r2.values()
-    np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-    s = am.stats()
-    assert s["mode"] == "arena"
-    assert s["donation"]["coverage"] > 0.0
-
-
-# ---------------------------------------------------------------------------
-# Lane chaining (the PipelinedModel AOT fast path)
-# ---------------------------------------------------------------------------
-
-
-class _FakeSeg:
-    def __init__(self, name, inputs):
-        self.output_name = name
-        self.input_names = tuple(inputs)
-
-    def params_slice(self, params):
-        return {}
-
-    def fn(self, seg_params, *xs):
-        return sum(xs)
-
-
-def test_build_chains_groups_dependency_closed_runs():
-    # lane: a<-x, b<-a, c<-(b, other), d<-c   with "other" from another lane
-    a, b = _FakeSeg("a", ["x"]), _FakeSeg("b", ["a"])
-    c, d = _FakeSeg("c", ["b", "other"]), _FakeSeg("d", ["c"])
-    chains = build_chains([a, b, c, d], graph_inputs=["x"])
-    assert [[s.output_name for s in ch] for ch in chains] == [["a", "b"], ["c", "d"]]
-
-
-def test_build_chains_all_graph_inputs_single_chain():
-    segs = [_FakeSeg(f"s{i}", ["x"]) for i in range(3)]
-    chains = build_chains(segs, graph_inputs=["x"])
-    assert len(chains) == 1 and len(chains[0]) == 3
-
-
-def test_chain_executor_bit_exact(compiled, io):
-    params, x = io
-    lane = list(compiled.segments)
-    chains = build_chains(lane, compiled.graph.inputs)
-    assert len(chains) == 1  # a pure chain collapses fully
-    ce = make_chain_executor(chains[0], params)
-    assert ce.ext_inputs == ("x",)
-    outs = ce.fn(jnp.asarray(x["x"]))
-    assert len(outs) == len(lane)
-    ref = compiled.run(params, x)
-    np.testing.assert_array_equal(
-        np.asarray(outs[-1]), np.asarray(list(ref.values())[0])
-    )
-
-
-def test_pipelined_aot_fast_path_bit_exact():
-    g = Graph(
-        "pipe_unit",
-        [
-            Node("a", "relu", ("x",), {"B": 1, "C": 16, "OY": 1, "OX": 1, "elem_bytes": 1}),
-            Node("b", "relu", ("a",), {"B": 1, "C": 16, "OY": 1, "OX": 1, "elem_bytes": 1}),
-            Node("c", "relu", ("a",), {"B": 1, "C": 16, "OY": 1, "OX": 1, "elem_bytes": 1}),
-            Node("d", "add", ("b", "c"), {"B": 1, "C": 16, "OY": 1, "OX": 1, "elem_bytes": 1}),
-        ],
-        {"x": (1, 16)},
-        ("d",),
-    )
-    cm = lower(dispatch(g, "gap9"))
-    params = {}
-    x = {"x": np.random.default_rng(1).normal(size=(1, 16)).astype("float32")}
-    pm = PipelinedModel(cm, aot=True)
-    n_chains = sum(len(c) for c in pm._chain_lanes.values())
-    n_segs = len(cm.segments)
-    assert 0 < n_chains <= n_segs
-    ref = cm.run(params, x)
-    got = pm.run(params, x)
-    for k in ref:
-        np.testing.assert_array_equal(np.asarray(ref[k]), np.asarray(got[k]))
-    stream = [{"x": x["x"] + i} for i in range(5)]
-    refs = [cm.run(params, s) for s in stream]
-    gots = pm.run_stream(params, stream)
-    for r, o in zip(refs, gots):
-        for k in r:
-            np.testing.assert_array_equal(np.asarray(r[k]), np.asarray(o[k]))
-    # executor cache: same params dict -> same executors
-    assert pm._executors_for(params) is pm._executors_for(params)
-
-
-# ---------------------------------------------------------------------------
-# MemoryPlan.arena_view invariants
-# ---------------------------------------------------------------------------
-
-
-def test_arena_view_scaling_invariants(compiled):
-    plan = compiled.memory_plan
-    view = plan.arena_view()
-    assert view.length_elems == plan.arena_bytes[view.home_level]
-    for name, off in view.offsets.items():
-        cap = view.capacities_elems[name]
-        assert off >= 0 and cap > 0
-        assert off + cap <= view.length_elems  # inside the arena
-        assert off == plan.buffers[name].offset
-        assert cap == plan.buffers[name].nbytes
+    assert d["aot"]["plan_aliasing"] == compiled.memory_plan.aliasing_summary()
+    assert 0.0 <= d["aot"]["intermediate_share"] <= 1.0
 
 
 def test_aliasing_summary_consistent(compiled):
-    s = compiled.memory_plan.aliasing_summary()
+    plan = compiled.memory_plan
+    s = plan.aliasing_summary()
     assert s["sum_buffer_bytes"] >= s["arena_peak_bytes"] > 0
+    assert s["arena_peak_bytes"] == plan.arena_bytes[plan.home_level]
+    for b in plan.buffers.values():  # every buffer inside the arena
+        assert b.offset >= 0 and b.nbytes > 0
+        assert b.offset + b.nbytes <= s["arena_peak_bytes"]
     assert s["bytes_saved_by_aliasing"] == s["sum_buffer_bytes"] - s["arena_peak_bytes"]
     assert s["aliased_pairs"] >= 0

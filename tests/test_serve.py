@@ -204,19 +204,41 @@ def test_server_bit_exact_per_request_and_reports():
     cm.attrs.pop("serve")  # don't leak replica state into other suites
 
 
-def test_server_pipeline_mode_bit_exact():
+def test_failed_round_resolves_its_requests_and_serves_on(monkeypatch):
+    """A round whose executable raises resolves every handle of that round
+    with the exception, and the replica serves the next request."""
     cm = _compiled()
     params, reqs = _io()
-    with ModelServer(
-        cm, params, batch_slots=2, stream_depth=2, mode="pipeline"
-    ) as srv:
-        handles = [srv.submit(r) for r in reqs[:5]]
-        outs = [h.result(timeout=120) for h in handles]
-    for i, out in enumerate(outs):
-        ref = cm.run(params, reqs[i])
-        for k in ref:
-            assert np.array_equal(np.asarray(ref[k]), np.asarray(out[k]))
+    srv = _pinned_server(cm, params, batch_slots=2, stream_depth=1)
+    real, failed = srv.batched.run_batch_async, []
+
+    def first_call_raises(p, inputs_list):
+        if not failed:
+            failed.append(len(inputs_list))
+            raise RuntimeError("executable failed")
+        return real(p, inputs_list)
+
+    monkeypatch.setattr(srv.batched, "run_batch_async", first_call_raises)
+    lost = [srv.submit(r) for r in reqs[:2]]  # queued before the loop runs
+    srv._thread = None
+    with srv:
+        for h in lost:
+            with pytest.raises(RuntimeError, match="executable failed"):
+                h.result(timeout=120)
+        out = srv.submit(reqs[2]).result(timeout=120)
+    assert failed == [2]  # both requests were in the failed round
+    ref = cm.run(params, reqs[2])
+    for k in ref:
+        assert np.array_equal(np.asarray(ref[k]), np.asarray(out[k]))
     cm.attrs.pop("serve")
+
+
+@pytest.mark.parametrize("bad", [{"batch_slots": 0}, {"stream_depth": 0}], ids=["batch_slots", "stream_depth"])
+def test_server_rejects_bad_slots_and_depth(bad):
+    cm = _compiled()
+    params, _ = _io()
+    with pytest.raises(ValueError, match=f"{next(iter(bad))} must be >= 1"):
+        ModelServer(cm, params, **bad)
 
 
 def test_priority_jumps_lane_order_in_a_round():
@@ -263,8 +285,7 @@ def _serve_one_round(srv, reqs, priorities) -> list[ServeRequest]:
     return batch
 
 
-@pytest.mark.parametrize("mode", ["aot", "pipeline"])
-def test_rounds_build_no_schedule_and_last_round_builds_it(monkeypatch, mode):
+def test_rounds_build_no_schedule_and_last_round_builds_it(monkeypatch):
     import repro.pipeline.schedule as schedule
 
     cm = _compiled()
@@ -276,7 +297,7 @@ def test_rounds_build_no_schedule_and_last_round_builds_it(monkeypatch, mode):
         return real(*args, **kwargs)
 
     monkeypatch.setattr(schedule, "schedule_stream", counting)
-    srv = _pinned_server(cm, params, batch_slots=2, stream_depth=2, mode=mode)
+    srv = _pinned_server(cm, params, batch_slots=2, stream_depth=2)
     _serve_one_round(srv, reqs, (1.0, 3.0, 2.0))
     batch = _serve_one_round(srv, reqs, (2.0, 1.0, 1.0, 4.0))
     assert calls == []
