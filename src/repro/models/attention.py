@@ -14,7 +14,6 @@ separate (t, h, w) position streams.
 from __future__ import annotations
 
 import math
-from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -26,11 +25,9 @@ from repro.models.layers import ParamSpec
 __all__ = [
     "attention_params",
     "attention",
-    "decode_attention",
     "rope_tables",
     "mrope_tables",
     "apply_rope",
-    "KVCache",
 ]
 
 
@@ -93,13 +90,6 @@ def attention_params(cfg: ModelConfig) -> dict:
         p["bk"] = ParamSpec((nkv * hd,), ("kv_heads",), cfg.dtype, init="zeros")
         p["bv"] = ParamSpec((nkv * hd,), ("kv_heads",), cfg.dtype, init="zeros")
     return p
-
-
-class KVCache(NamedTuple):
-    """Decode-time cache for one attention layer (or stacked layers)."""
-
-    k: jax.Array  # (B, S_max, KV, hd)
-    v: jax.Array
 
 
 # ---------------------------------------------------------------------------
@@ -203,43 +193,3 @@ def attention(
     out = constrain(out.astype(x.dtype), "batch", "seq", "heads", None)
     y = out.reshape(B, S, -1) @ params["wo"]
     return constrain(y, "batch", "seq", None)
-
-
-def decode_attention(
-    params: dict,
-    x: jax.Array,  # (B, 1, D)
-    cache: KVCache,
-    position: jax.Array,  # scalar int32: index of the new token
-    cfg: ModelConfig,
-    *,
-    window: int | None = None,
-) -> tuple[jax.Array, KVCache]:
-    """One-token decode against a (B, S_max, KV, hd) cache."""
-    B = x.shape[0]
-    hd, nh, nkv = cfg.head_dim_, cfg.n_heads, cfg.kv_heads
-    q, k_new, v_new = _qkv(params, x, cfg)
-    pos = jnp.asarray(position, jnp.int32)[None]  # (1,)
-    sin, cos = rope_tables(pos, hd, cfg.rope_theta)  # (1, hd/2)
-    if cfg.pos_kind != "none":
-        q = apply_rope(q, sin, cos)
-        k_new = apply_rope(k_new, sin, cos)
-
-    k = jax.lax.dynamic_update_slice(cache.k, k_new.astype(cache.k.dtype), (0, position, 0, 0))
-    v = jax.lax.dynamic_update_slice(cache.v, v_new.astype(cache.v.dtype), (0, position, 0, 0))
-    k = constrain(k, "batch", None, "kv_heads", None)
-    v = constrain(v, "batch", None, "kv_heads", None)
-
-    S_max = k.shape[1]
-    g = nh // nkv
-    qf = (q.astype(jnp.float32) * cfg.attn_scale).reshape(B, 1, nkv, g, hd)
-    s = jnp.einsum("bqkgd,bskd->bqkgs", qf, k.astype(jnp.float32))
-    k_pos = jnp.arange(S_max)
-    mask = k_pos <= position
-    if window is not None:
-        mask &= k_pos > position - window
-    s = jnp.where(mask[None, None, None, None, :], s, -1e30)
-    p = jax.nn.softmax(s, axis=-1)
-    out = jnp.einsum("bqkgs,bskd->bqkgd", p, v.astype(jnp.float32))
-    out = out.reshape(B, 1, nh * hd).astype(x.dtype)
-    y = out @ params["wo"]
-    return constrain(y, "batch", "seq", None), KVCache(k, v)

@@ -38,14 +38,34 @@ import jax.numpy as jnp
 
 from repro.distributed.sharding import constrain
 from repro.models import attention as attn_mod
-from repro.models.attention import KVCache, attention, decode_attention, mrope_tables, rope_tables
+from repro.models.attention import attention, mrope_tables, rope_tables
 from repro.models.config import ModelConfig
 from repro.models.layers import ParamSpec, embed_params, init_from_specs, mlp, mlp_params, rmsnorm, spec_shapes
 from repro.models.moe import moe_ffn, moe_params, moe_share_ffn
 from repro.models.rglru import rglru_block, rglru_decode_step, rglru_params, rglru_state_init
 from repro.models.ssd import ssd_block, ssd_decode_step, ssd_params, ssd_state_init
 
-__all__ = ["LM", "StackSpec"]
+__all__ = ["KV_BUCKET", "LM", "StackSpec", "kv_prefixes", "kv_read"]
+
+# a decode step's global attention reads K and V over a prefix of the
+# cache that is a multiple of this many positions (the last, the whole
+# cache): one static shape per bucket, picked on the device
+KV_BUCKET = 512
+
+
+def kv_prefixes(length: int) -> tuple[int, ...]:
+    """The prefixes of a global-attention cache of ``length`` positions
+    that a decode step may read: each multiple of :data:`KV_BUCKET` below
+    ``length``, then ``length``."""
+    return tuple(range(KV_BUCKET, length, KV_BUCKET)) + (length,)
+
+
+def kv_read(position: int, length: int) -> int:
+    """The prefix of :func:`kv_prefixes` that a decode step at ``position``
+    reads: the shortest that holds ``position``.  The step picks it with
+    ``lax.switch(position // KV_BUCKET, ...)``, whose index clamps to the
+    last prefix as the ``min`` here does."""
+    return min(length, (position // KV_BUCKET + 1) * KV_BUCKET)
 
 
 @dataclass(frozen=True)
@@ -330,9 +350,13 @@ class LM:
         if btype in ("attn", "local_attn"):
             length = min(max_len, cfg.local_window) if btype == "local_attn" else max_len
             kv, hd = cfg.kv_heads, cfg.head_dim_
+            # heads before positions, the layout the decode step's
+            # contractions read: a prefix of positions is then read in
+            # place, where with positions first XLA lays the whole buffer
+            # out anew in each branch of the live-prefix switch
             return {
-                "k": jnp.zeros((batch, length, kv, hd), dt),
-                "v": jnp.zeros((batch, length, kv, hd), dt),
+                "k": jnp.zeros((batch, kv, length, hd), dt),
+                "v": jnp.zeros((batch, kv, length, hd), dt),
                 "pos": jnp.full((length,), -1, jnp.int32),
             }
         if btype == "rglru":
@@ -357,8 +381,8 @@ class LM:
         """Logical sharding axes mirroring _layer_cache_spec leaves."""
         if btype in ("attn", "local_attn"):
             return {
-                "k": ("layers", "batch", None, "kv_heads", None),
-                "v": ("layers", "batch", None, "kv_heads", None),
+                "k": ("layers", "batch", "kv_heads", None, None),
+                "v": ("layers", "batch", "kv_heads", None, None),
                 "pos": ("layers", None),
             }
         if btype == "rglru":
@@ -390,7 +414,7 @@ class LM:
         h = rmsnorm(x, bp["norm1"], cfg.norm_eps)
         if btype in ("attn", "local_attn"):
             window = cfg.local_window if btype == "local_attn" else None
-            length = lc["k"].shape[1]
+            length = lc["k"].shape[2]
             slot = position % length if btype == "local_attn" else position
             out, lc = self._decode_attn(bp["attn"], h, lc, slot, position, window)
         elif btype == "rglru":
@@ -401,7 +425,8 @@ class LM:
         return x, lc, rows
 
     def _decode_attn(self, ap: dict, x, lc: dict, slot, position, window):
-        """Ring-buffer-aware single-token attention."""
+        """Single-token attention: a global layer reads the live prefix of
+        its cache (:func:`kv_read`), a local layer its whole ring."""
         cfg = self.cfg
         B = x.shape[0]
         hd, nh, nkv = cfg.head_dim_, cfg.n_heads, cfg.kv_heads
@@ -411,21 +436,34 @@ class LM:
             sin, cos = rope_tables(pos_arr, hd, cfg.rope_theta)
             q = attn_mod.apply_rope(q, sin, cos)
             k_new = attn_mod.apply_rope(k_new, sin, cos)
-        k = jax.lax.dynamic_update_slice(lc["k"], k_new.astype(lc["k"].dtype), (0, slot, 0, 0))
-        v = jax.lax.dynamic_update_slice(lc["v"], v_new.astype(lc["v"].dtype), (0, slot, 0, 0))
+        k_new, v_new = (jnp.swapaxes(a, 1, 2).astype(lc["k"].dtype) for a in (k_new, v_new))
+        k = jax.lax.dynamic_update_slice(lc["k"], k_new, (0, 0, slot, 0))
+        v = jax.lax.dynamic_update_slice(lc["v"], v_new, (0, 0, slot, 0))
         posbuf = jax.lax.dynamic_update_slice(lc["pos"], pos_arr, (slot,))
-        k = constrain(k, "batch", None, "kv_heads", None)
-        v = constrain(v, "batch", None, "kv_heads", None)
+        k = constrain(k, "batch", "kv_heads", None, None)
+        v = constrain(v, "batch", "kv_heads", None, None)
 
         g = nh // nkv
         qf = (q.astype(jnp.float32) * cfg.attn_scale).reshape(B, 1, nkv, g, hd)
-        s = jnp.einsum("bqkgd,bskd->bqkgs", qf, k.astype(jnp.float32))
-        valid = (posbuf >= 0) & (posbuf <= position)
-        if window is not None:
-            valid &= posbuf > position - window
-        s = jnp.where(valid[None, None, None, None, :], s, -1e30)
-        p = jax.nn.softmax(s, axis=-1)
-        out = jnp.einsum("bqkgs,bskd->bqkgd", p, v.astype(jnp.float32))
+
+        def attend(k, v, posbuf):
+            s = jnp.einsum("bqkgd,bksd->bqkgs", qf, k.astype(jnp.float32))
+            valid = (posbuf >= 0) & (posbuf <= position)
+            if window is not None:
+                valid &= posbuf > position - window
+            s = jnp.where(valid[None, None, None, None, :], s, -1e30)
+            p = jax.nn.softmax(s, axis=-1)
+            return jnp.einsum("bqkgs,bksd->bqkgd", p, v.astype(jnp.float32))
+
+        if window is None:
+            # global attention writes position p at slot p, so no slot past
+            # ``position`` is valid yet: read only the live prefix (kv_read)
+            branches = [
+                lambda k, v, pb, n=n: attend(k[:, :, :n], v[:, :, :n], pb[:n]) for n in kv_prefixes(k.shape[2])
+            ]
+            out = jax.lax.switch(position // KV_BUCKET, branches, k, v, posbuf)
+        else:  # a ring: once it wraps, every slot is live
+            out = attend(k, v, posbuf)
         out = out.reshape(B, 1, nh * hd).astype(x.dtype)
         y = out @ ap["wo"]
         return constrain(y, "batch", "seq", None), {"k": k, "v": v, "pos": posbuf}
@@ -508,16 +546,17 @@ class LM:
                 sin, cos = rope
                 if sin is not None:
                     k = attn_mod.apply_rope(k, sin, cos)
-                L = lc["k"].shape[1]
+                k, v = jnp.swapaxes(k, 1, 2), jnp.swapaxes(v, 1, 2)  # the cache's (B, KV, S, hd)
+                L = lc["k"].shape[2]
                 if L <= S:
                     # ring-buffer (local) or exactly-sized cache: keep the
                     # last L entries (requires S % L == 0 for the ring
                     # slot mapping; checked by the serving engine)
-                    kk, vv = k[:, -L:], v[:, -L:]
+                    kk, vv = k[:, :, -L:], v[:, :, -L:]
                     pp = jnp.arange(S)[-L:].astype(jnp.int32)
                 else:
                     # head-room for decode: prompt in slots [0, S)
-                    pad = ((0, 0), (0, L - S), (0, 0), (0, 0))
+                    pad = ((0, 0), (0, 0), (0, L - S), (0, 0))
                     kk = jnp.pad(k, pad)
                     vv = jnp.pad(v, pad)
                     pp = jnp.pad(jnp.arange(S, dtype=jnp.int32), (0, L - S), constant_values=-1)

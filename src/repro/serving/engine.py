@@ -29,7 +29,9 @@
   t's tokens, unless the caller edits rows between steps (refill,
   sampling), as ``run`` does.
 * Traced (``repro.obs``) as ``lm.prefill`` (``rows``, ``tokens``) a row
-  group, ``lm.decode`` (``slots``, ``pos``) the dispatch of a step, and
+  group, ``lm.decode`` (``slots``, ``pos``, ``kv_read``: the cached
+  positions each global attention layer reads, :func:`repro.models.kv_read`)
+  the dispatch of a step, and
   ``lm.fetch`` (``tokens``, ``expert_rows``) the host's wait for a step's
   tokens.
 
@@ -51,7 +53,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro import obs
-from repro.models import LM
+from repro.models import LM, kv_read
 from repro.obs.log import MatchWarning
 from repro.obs.log import warn as obs_warn
 
@@ -105,6 +107,8 @@ class ServeEngine:
         self._queue: "queue.Queue[Request]" = queue.Queue()
         self._pending: list[Request] = []  # popped but not yet slotted
         axes = model.cache_axes()
+        # the global attention layers' cache length (0 without one)
+        self._kv_len = max_len if "attn" in model.cfg.layer_pattern() else 0
 
         def lm_prefill(params, cache, tokens, row):
             """Prefill ``tokens`` (g, S) into rows [row, row + g) of the
@@ -216,7 +220,7 @@ class ServeEngine:
         tr = obs.get_tracer()
 
         def dispatch():
-            with tr.span("lm.decode", slots=B, pos=ls.pos):
+            with tr.span("lm.decode", slots=B, pos=ls.pos, kv_read=kv_read(ls.pos, self._kv_len)):
                 ls.tokens, out, lg, ls.cache = self._step(
                     self.params, ls.cache, ls.tokens, np.int32(ls.pos), ls.keep
                 )
